@@ -30,7 +30,6 @@ Usage::
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import subprocess
 import sys
@@ -43,10 +42,9 @@ sys.path.insert(0, str(REPO_ROOT / "src"))
 from repro.core.types import ClusterSpec                         # noqa: E402
 from repro.experiments.runner import (ExperimentSpec, TraceRef,  # noqa: E402
                                       simulate_cell)
-from repro.simcluster.sim import ClusterSim                      # noqa: E402
+from repro.experiments.surrogate import build_inputs             # noqa: E402
 from repro.simcluster.surrogate import (SURROGATE_ENGINE_ID,     # noqa: E402
-                                        build_cell, lower_policy,
-                                        run_batch)
+                                        run_batch, use_compile_cache)
 
 EVENT_ENGINE_ID = "simcluster.sim/incremental-index"
 POLICIES = ("proposed", "fair", "fifo", "delay", "edf_nopark")
@@ -86,22 +84,7 @@ def bench(n_seeds: int, event_sample: int, commit: str) -> dict:
     print(f"[bench] building {len(cells)} surrogate cells "
           f"({len(POLICIES)} policies x {n_seeds} seeds) ...", flush=True)
     t0 = time.perf_counter()
-    resolved: dict = {}
-    base: dict = {}
-    inputs = []
-    for cell in cells:
-        tkey = (id(cell.trace), cell.seed)
-        if tkey not in resolved:
-            resolved[tkey] = cell.trace.resolve(cell.seed)
-        trace = resolved[tkey]
-        bkey = (id(trace), id(cell.cluster), cell.seed)
-        if bkey not in base:
-            base[bkey] = build_cell(trace, cell.cluster, cell.scheduler,
-                                    cell.seed)
-            inputs.append(base[bkey])
-        else:
-            inputs.append(dataclasses.replace(
-                base[bkey], policy=lower_policy(cell.scheduler)))
+    _, inputs = build_inputs(cells)
     t_build = time.perf_counter() - t0
     # one warmup batch triggers XLA compilation for the bucket; the timed
     # run below then measures steady-state sweep throughput (a repeat
@@ -161,6 +144,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     commit = git_commit()
+    use_compile_cache()
     entry = bench(n_seeds=20 if args.quick else 200,
                   event_sample=2 if args.quick else 4, commit=commit)
     entry["mode"] = "quick" if args.quick else "full"

@@ -53,6 +53,7 @@ from repro.experiments.runner import (ExperimentSpec, TraceRef, run_experiment)
 from repro.experiments.stats import (compare_completion_by_workload,
                                      compare_deadlines, compare_throughput)
 from repro.simcluster.largescale import FLEET_SHAPES
+from repro.simcluster.surrogate import use_compile_cache
 from repro.simcluster.traces import (PRESETS, Trace, TraceConfig,
                                      TraceImportError, generate_trace,
                                      import_swim_file, paper_trace)
@@ -764,6 +765,7 @@ def main(argv=None) -> int:
     p.set_defaults(func=cmd_paper)
 
     args = ap.parse_args(argv)
+    use_compile_cache()
     return args.func(args)
 
 

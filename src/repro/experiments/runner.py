@@ -23,6 +23,8 @@ from __future__ import annotations
 import hashlib
 import json
 import multiprocessing
+import os
+import sys
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -218,6 +220,26 @@ def _cell_paths(cache_dir: Path, cell: Cell) -> Tuple[Path, Path]:
     return cell_dir, cell_dir / f"seed{cell.seed}.json"
 
 
+def _cpu_only_worker() -> None:
+    """Pool initializer: keep an event-engine worker off the accelerator.
+
+    A chip belongs to one process, and the parent that runs the surrogate
+    holds it; a worker that initialised a TPU backend would fail or hang.
+    The event engine never needs JAX, so pin every worker to the CPU
+    before anything it runs can import JAX (and, should the spawn
+    bootstrap have imported it already, before any backend starts)."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
+
+
+def worker_pool(processes: int):
+    """Spawn pool of CPU-only workers (see :func:`_cpu_only_worker`).
+    Spawn, not fork: the parent may hold JAX or threading state."""
+    ctx = multiprocessing.get_context("spawn")
+    return ctx.Pool(processes=processes, initializer=_cpu_only_worker)
+
+
 def run_experiment(spec: ExperimentSpec,
                    cache_dir: Union[str, Path],
                    *, workers: int = 0,
@@ -244,10 +266,7 @@ def run_experiment(spec: ExperimentSpec,
 
     if todo:
         if workers > 1 and len(todo) > 1:
-            # spawn, not fork: the parent may hold jax/threading state (e.g.
-            # under pytest), and the worker import chain is jax-free and cheap
-            ctx = multiprocessing.get_context("spawn")
-            with ctx.Pool(processes=min(workers, len(todo))) as pool:
+            with worker_pool(min(workers, len(todo))) as pool:
                 results = pool.map(simulate_cell, todo)
         else:
             results = [simulate_cell(cell) for cell in todo]

@@ -11,7 +11,9 @@ content-hash cache layout (``<cell_hash>/meta.json`` + ``seed<k>.json``)
 but the descriptor carries an extra ``"engine": SURROGATE_ENGINE_ID`` key
 the event engine's descriptors never have, so the two engines' hashes are
 disjoint by construction: a surrogate sweep can never serve — or pollute —
-an event-engine cell (pinned by ``tests/test_experiments.py``).
+an event-engine cell (pinned by ``tests/test_experiments.py``).  Off the
+CPU the descriptor also names the platform, so chip and CPU cells never
+serve each other either.
 
 **Calibration gate.**  The fluid model is only trusted where the
 differential wall (``tests/test_surrogate.py``) has shown its policy-vs-
@@ -42,10 +44,11 @@ from repro.experiments.runner import (Cell, ExperimentSpec, SweepReport,
                                       run_experiment)
 from repro.experiments.stats import PairedComparison, compare_throughput
 from repro.simcluster.surrogate import (SURROGATE_ENGINE_ID,
+                                        SurrogateCellInputs,
                                         SurrogateResult,
                                         SurrogateUnsupported, build_cell,
                                         lower_policy, run_batch)
-from repro.simcluster.traces import _dumps
+from repro.simcluster.traces import Trace, _dumps
 
 #: the differential wall's verdict, pinned: (preset, fleet shape) → the
 #: policy labels whose policy-vs-fair gain the surrogate reproduces inside
@@ -64,11 +67,27 @@ CALIBRATED: Dict[Tuple[str, str], Tuple[str, ...]] = {
 CALIBRATION_SEEDS: Tuple[int, ...] = (0, 1, 2, 3)
 
 
+def _device_platform() -> str:
+    """Platform the kernel runs on: the default device's, which
+    ``jax.default_device`` can steer away from the default backend."""
+    import jax
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend()
+    return dev if isinstance(dev, str) else dev.platform
+
+
 def surrogate_descriptor(cell: Cell) -> Dict[str, object]:
-    """The event cell descriptor plus the engine-id key — the *only*
-    difference, so one grid maps to two parallel hash families."""
+    """The event cell descriptor plus the engine-id key, so one grid maps
+    to two parallel hash families.  Off the CPU the platform joins the
+    key as well: an accelerator's ``exp``/reductions may differ from the
+    CPU's by ULPs, so a cell is never served to a platform that did not
+    integrate it (CPU hashes stay as they always were)."""
     d = cell.descriptor()
     d["engine"] = SURROGATE_ENGINE_ID
+    platform = _device_platform()
+    if platform != "cpu":
+        d["platform"] = platform
     return d
 
 
@@ -107,6 +126,34 @@ def _record(cell: Cell, res: SurrogateResult, trace_name: str,
         jobs=jobs, policy=cell.scheduler.to_dict())
 
 
+def build_inputs(cells: Sequence[Cell]
+                 ) -> Tuple[List[Trace], List[SurrogateCellInputs]]:
+    """Resolve each cell's trace and compile it to kernel inputs (host
+    work, numpy).  Returns the resolved traces and the inputs, both in
+    cell order."""
+    resolved: Dict[Tuple[int, int], Trace] = {}
+    for cell in cells:
+        key = (id(cell.trace), cell.seed)
+        if key not in resolved:
+            resolved[key] = cell.trace.resolve(cell.seed)
+    traces = [resolved[(id(cell.trace), cell.seed)] for cell in cells]
+    # the expensive per-job compilation (block placements, jitter) is
+    # policy-independent: build once per (trace, seed, cluster) and
+    # swap only the lowered policy across the grid's policy columns
+    base: Dict[Tuple[int, int, int], SurrogateCellInputs] = {}
+    inputs = []
+    for cell, trace in zip(cells, traces):
+        key = (id(trace), id(cell.cluster), cell.seed)
+        if key not in base:
+            base[key] = build_cell(trace, cell.cluster,
+                                   cell.scheduler, cell.seed)
+            inputs.append(base[key])
+        else:
+            inputs.append(dataclasses.replace(
+                base[key], policy=lower_policy(cell.scheduler)))
+    return traces, inputs
+
+
 def run_surrogate(spec: ExperimentSpec, cache_dir: Union[str, Path],
                   *, progress=None) -> SweepReport:
     """Run (or re-serve from cache) every cell of ``spec`` through the
@@ -137,26 +184,7 @@ def run_surrogate(spec: ExperimentSpec, cache_dir: Union[str, Path],
                  f"{len(records)} cached, {len(todo)} to integrate")
     if todo:
         t0 = time.perf_counter()
-        resolved: Dict[Tuple[int, int], object] = {}
-        for cell in todo:
-            key = (id(cell.trace), cell.seed)
-            if key not in resolved:
-                resolved[key] = cell.trace.resolve(cell.seed)
-        traces = [resolved[(id(cell.trace), cell.seed)] for cell in todo]
-        # the expensive per-job compilation (block placements, jitter) is
-        # policy-independent: build once per (trace, seed, cluster) and
-        # swap only the lowered policy across the grid's policy columns
-        base: Dict[Tuple[int, int, int], object] = {}
-        inputs = []
-        for cell, trace in zip(todo, traces):
-            key = (id(trace), id(cell.cluster), cell.seed)
-            if key not in base:
-                base[key] = build_cell(trace, cell.cluster,
-                                       cell.scheduler, cell.seed)
-                inputs.append(base[key])
-            else:
-                inputs.append(dataclasses.replace(
-                    base[key], policy=lower_policy(cell.scheduler)))
+        traces, inputs = build_inputs(todo)
         results = run_batch(inputs)
         per_cell = (time.perf_counter() - t0) / len(todo)
         for cell, trace, res in zip(todo, traces, results):

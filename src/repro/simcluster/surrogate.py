@@ -38,17 +38,23 @@ reduce-aware latch (``overload: reduce_aware``).  Those live on event-level
 signals (per-machine donor timing) with no fluid equivalent; the policies
 ``adaptive`` and ``adaptive_ra`` stay oracle-only.
 
-Determinism contract (pinned by ``tests/test_surrogate.py``): per
-(config, seed) the result is byte-stable on CPU; a batch of one through
+Determinism contract, pinned on CPU by ``tests/test_surrogate.py`` and on
+one TPU v5e by ``chip_smoke.py`` (80 fleet cells at 200x2): per (config,
+seed) the result is byte-stable on a platform; a batch of one through
 ``vmap`` is bit-identical to the unbatched kernel; and a cell's result is
-invariant to the batch it rides in — padding buckets (``_bucket``) are a
-function of the cell alone, never of its batch mates.
+invariant to the batch it rides in and to the sub-batch cap — padding
+buckets (``_bucket``) are a function of the cell alone, never of its batch
+mates.  Across platforms the result is not bitwise: on the v5e the
+launch-mass sums (hence locality) differ from the CPU's by ~3e-7 while
+finish times, finished jobs and met deadlines match, so the sweep cache
+keys surrogate cells on the platform off the CPU.
 """
 from __future__ import annotations
 
 import math
 import os
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -646,11 +652,35 @@ def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
 
 _KERNEL_CACHE: Dict[Tuple[int, int, bool, bool], object] = {}
 
-#: cells per vmapped sub-batch in run_batch — large enough to amortize
-#: dispatch, small enough that the scan carry stays cache-resident.
+#: the checkout root (``src/repro/simcluster/`` → three levels up)
+_CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Call from an entry point before the first compile, never at import.
+    When ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and
+    this sets no other directory.  Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache/``: the directory is part of what a later
+    process must find again, so it never takes a temporary name."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(_CHECKOUT / ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
+
+
+#: cells per vmapped sub-batch in run_batch.  On the CPU it is large enough
+#: to amortize dispatch, small enough that the scan carry stays
+#: cache-resident.  On one TPU v5e the per-cell cost is nearly flat in the
+#: cap: 80 fleet cells (1024 jobs x 4096 steps) took 14.4 s at 64, 13.4 s
+#: at 16 and 18.6 s at 1, so the device's per-step work grows with the
+#: batch and the cap mostly decides how many shapes compile (each
+#: sub-batch size, remainder included, is its own compile).
 #: Overridable per-call (``run_batch(..., max_batch=...)``) or process-wide
-#: via ``REPRO_SURROGATE_MAX_BATCH``; per-cell results are independent of
-#: the sub-batch split, so overrides only move the dispatch/cache tradeoff.
+#: via ``REPRO_SURROGATE_MAX_BATCH``; per-cell results are bit-identical
+#: at every cap, on the CPU and on the chip.
 _MAX_BATCH = 64
 
 

@@ -137,6 +137,17 @@ def test_worker_pool_matches_inline(tmp_path):
         == [strip_wall(r) for r in inline.records]
 
 
+def test_pool_workers_stay_off_the_accelerator(monkeypatch):
+    """Event-engine workers must never contend for the parent's chip: each
+    spawn worker is pinned to the CPU backend whatever the parent's env."""
+    import os
+    from repro.experiments.runner import worker_pool
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with worker_pool(2) as pool:
+        seen = pool.map(os.getenv, ["JAX_PLATFORMS"] * 2)
+    assert seen == ["cpu", "cpu"]
+
+
 def test_rows_trace_ref_resolves_and_caches(tmp_path):
     """The rows kind (hand-built mixes, e.g. the Fig.-2 grid) flows through
     the cache like any other trace and re-rolls placement per sim seed."""

@@ -7,6 +7,7 @@ fall inside the event oracle's 95% paired-bootstrap CI on identical
 (trace, seed) cells.  A preset enters the allowlist only by passing here —
 and drifts out loudly, not silently, when either engine changes."""
 import json
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -117,6 +118,35 @@ def test_surrogate_descriptor_carries_engine_id(tmp_path):
         d = surrogate_descriptor(cell)
         d.pop("engine")
         assert d == cell.descriptor()
+
+
+def test_surrogate_hash_keys_on_accelerator_platform(monkeypatch):
+    """A cell integrated on an accelerator hashes apart from the CPU's, so
+    neither is ever served the other's result; CPU hashes stay unkeyed."""
+    import repro.experiments.surrogate as sur_mod
+    cell = next(_small_spec(seeds=(0,)).cells())
+    cpu = surrogate_hash(cell)
+    assert "platform" not in surrogate_descriptor(cell)
+    monkeypatch.setattr(sur_mod, "_device_platform", lambda: "tpu")
+    assert surrogate_descriptor(cell)["platform"] == "tpu"
+    assert surrogate_hash(cell) != cpu
+
+
+def test_compile_cache_dir_is_fixed_unless_env_names_one(monkeypatch):
+    """Unset, the persistent compile cache sits at <checkout>/.jax_cache;
+    with JAX_COMPILATION_CACHE_DIR set, the helper sets no directory."""
+    import jax
+    import repro.simcluster.surrogate as sg
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        checkout = Path(__file__).resolve().parents[1]
+        assert sg.use_compile_cache() == str(checkout / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", prev)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/from/env")
+        assert sg.use_compile_cache() == prev
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 def test_unsupported_grid_rejected_before_any_work(tmp_path):
