@@ -1,0 +1,7 @@
+"""device_idle_share.query: 1 - (union of device-op intervals) / traced
+window, from the device trace."""
+from harness import layers
+
+
+def read(ctx):
+    return layers.idle_share(ctx)
