@@ -39,10 +39,11 @@ Examples::
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import sys
 from pathlib import Path
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 from repro.core.policies import (PolicyError, PolicySpec,
                                  registered_policies, smoke_test_policies)
@@ -414,6 +415,16 @@ def cmd_policies(args) -> int:
     return 0
 
 
+def _profiled(log_dir: Optional[Path]):
+    """A profiler trace under ``log_dir`` (an ``.xplane.pb`` for
+    TensorBoard and a ``perfetto_trace.json.gz`` for Perfetto), or nothing
+    without a directory."""
+    if log_dir is None:
+        return contextlib.nullcontext()
+    import jax
+    return jax.profiler.trace(str(log_dir), create_perfetto_trace=True)
+
+
 def cmd_surrogate(args) -> int:
     from repro.experiments import surrogate as sur_mod
     from repro.simcluster.surrogate import SurrogateUnsupported
@@ -449,8 +460,10 @@ def cmd_surrogate(args) -> int:
                               traces=base.traces, clusters=base.clusters,
                               schedulers=pols + ("fair",), seeds=seeds)
         try:
-            rep = sur_mod.run_surrogate(
-                spec, args.cache, progress=print if args.verbose else None)
+            with _profiled(args.profile):
+                rep = sur_mod.run_surrogate(
+                    spec, args.cache,
+                    progress=print if args.verbose else None)
         except SurrogateUnsupported as e:
             raise SystemExit(f"surrogate: {e}")
         by = rep.by_scheduler()
@@ -695,6 +708,11 @@ def main(argv=None) -> int:
                     help="skip the paired event-oracle calibration pass")
     sg.add_argument("--workers", type=int, default=0,
                     help="pool size for the oracle side of calibration")
+    sg.add_argument("--profile", type=Path, default=None, metavar="DIR",
+                    help="record each sweep with the JAX profiler under "
+                         "DIR (TensorBoard's profile plugin or Perfetto "
+                         "opens it): the program's repro.* spans and the "
+                         "kernel's stages")
     sg.add_argument("--verbose", action="store_true")
     sg.set_defaults(func=cmd_surrogate)
 

@@ -38,6 +38,7 @@ from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 from repro.core.policies import PolicySpec
+from repro.core.tracing import request, span
 from repro.experiments.metrics import JobRecord, RunRecord
 from repro.experiments.regimes import regime_spec
 from repro.experiments.runner import (Cell, ExperimentSpec, SweepReport,
@@ -131,27 +132,30 @@ def build_inputs(cells: Sequence[Cell]
     """Resolve each cell's trace and compile it to kernel inputs (host
     work, numpy).  Returns the resolved traces and the inputs, both in
     cell order."""
-    resolved: Dict[Tuple[int, int], Trace] = {}
-    for cell in cells:
-        key = (id(cell.trace), cell.seed)
-        if key not in resolved:
-            resolved[key] = cell.trace.resolve(cell.seed)
-    traces = [resolved[(id(cell.trace), cell.seed)] for cell in cells]
-    # the expensive per-job compilation (block placements, jitter) is
-    # policy-independent: build once per (trace, seed, cluster) and
-    # swap only the lowered policy across the grid's policy columns
-    base: Dict[Tuple[int, int, int], SurrogateCellInputs] = {}
-    inputs = []
-    for cell, trace in zip(cells, traces):
-        key = (id(trace), id(cell.cluster), cell.seed)
-        if key not in base:
-            base[key] = build_cell(trace, cell.cluster,
-                                   cell.scheduler, cell.seed)
-            inputs.append(base[key])
-        else:
-            inputs.append(dataclasses.replace(
-                base[key], policy=lower_policy(cell.scheduler)))
-    return traces, inputs
+    with span("repro.surrogate.build"):
+        resolved: Dict[Tuple[int, int], Trace] = {}
+        for cell in cells:
+            key = (id(cell.trace), cell.seed)
+            if key not in resolved:
+                with span("repro.surrogate.resolve"):
+                    resolved[key] = cell.trace.resolve(cell.seed)
+        traces = [resolved[(id(cell.trace), cell.seed)] for cell in cells]
+        # the expensive per-job compilation (block placements, jitter) is
+        # policy-independent: build once per (trace, seed, cluster) and
+        # swap only the lowered policy across the grid's policy columns
+        base: Dict[Tuple[int, int, int], SurrogateCellInputs] = {}
+        inputs = []
+        for cell, trace in zip(cells, traces):
+            key = (id(trace), id(cell.cluster), cell.seed)
+            if key not in base:
+                with span("repro.surrogate.build_cell"):
+                    base[key] = build_cell(trace, cell.cluster,
+                                           cell.scheduler, cell.seed)
+                inputs.append(base[key])
+            else:
+                inputs.append(dataclasses.replace(
+                    base[key], policy=lower_policy(cell.scheduler)))
+        return traces, inputs
 
 
 def run_surrogate(spec: ExperimentSpec, cache_dir: Union[str, Path],
@@ -168,25 +172,36 @@ def run_surrogate(spec: ExperimentSpec, cache_dir: Union[str, Path],
     """
     for sched in spec.schedulers:
         lower_policy(sched)          # raises SurrogateUnsupported
-    cache_dir = Path(cache_dir)
+    with request(), span("repro.surrogate.sweep"):
+        return _sweep(spec, Path(cache_dir), progress)
+
+
+def _sweep(spec: ExperimentSpec, cache_dir: Path, progress) -> SweepReport:
     cache_dir.mkdir(parents=True, exist_ok=True)
     records: List[RunRecord] = []
     todo: List[Cell] = []
-    for cell in spec.cells():
-        _, result_path = _cell_paths(cache_dir, cell)
-        if result_path.exists():
-            records.append(RunRecord.from_dict(
-                json.loads(result_path.read_text())))
-        else:
-            todo.append(cell)
+    with span("repro.surrogate.cache_lookup"):
+        for cell in spec.cells():
+            _, result_path = _cell_paths(cache_dir, cell)
+            if result_path.exists():
+                records.append(RunRecord.from_dict(
+                    json.loads(result_path.read_text())))
+            else:
+                todo.append(cell)
     if progress:
         progress(f"[{spec.name}] {spec.n_cells()} surrogate cells: "
                  f"{len(records)} cached, {len(todo)} to integrate")
+    traces, results, per_cell = [], [], 0.0
     if todo:
         t0 = time.perf_counter()
         traces, inputs = build_inputs(todo)
         results = run_batch(inputs)
         per_cell = (time.perf_counter() - t0) / len(todo)
+        if progress:
+            progress(f"  integrated {len(todo)} cells in "
+                     f"{per_cell * len(todo):.2f}s "
+                     f"({1.0 / per_cell:.0f} cells/s)")
+    with span("repro.surrogate.records"):
         for cell, trace, res in zip(todo, traces, results):
             rec = _record(cell, res, trace.name, trace.seed, per_cell)
             cell_dir, result_path = _cell_paths(cache_dir, cell)
@@ -198,12 +213,8 @@ def run_surrogate(spec: ExperimentSpec, cache_dir: Union[str, Path],
                     + "\n")
             result_path.write_text(_dumps(rec.to_dict()) + "\n")
             records.append(rec)
-        if progress:
-            progress(f"  integrated {len(todo)} cells in "
-                     f"{per_cell * len(todo):.2f}s "
-                     f"({1.0 / per_cell:.0f} cells/s)")
-    records.sort(key=lambda r: (r.trace_name, r.trace_seed,
-                                _dumps(r.cluster), r.scheduler, r.seed))
+        records.sort(key=lambda r: (r.trace_name, r.trace_seed,
+                                    _dumps(r.cluster), r.scheduler, r.seed))
     return SweepReport(spec_name=spec.name, records=records,
                        simulated=len(todo),
                        cached=spec.n_cells() - len(todo))

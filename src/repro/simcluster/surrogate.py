@@ -53,6 +53,7 @@ from __future__ import annotations
 
 import math
 import os
+import re
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -60,6 +61,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.policies import PolicySpec
+from repro.core.tracing import span
 from repro.core.types import AdaptiveConfig, ClusterSpec
 from repro.simcluster.traces import Trace, _stable_seed
 
@@ -139,6 +141,10 @@ _FAIR_ITERS = 8
 #: their quantized service time via a (jobs × _RING) delay ring; service
 #: lags clip to _RING − 1 (= 378 s at DT, far above any per-task time)
 _RING = 64
+#: steps per early-exit chunk: the kernel integrates whole chunks and
+#: counts them per cell (its ``chunks`` output); step buckets are whole
+#: chunks
+CHUNK = 256
 _EPS = 1e-6
 _INF = np.float32(3.0e9)
 
@@ -249,7 +255,7 @@ class SurrogateCellInputs:
         return _bucket(self.n_jobs, 8)
 
     def n_steps(self) -> int:
-        return _bucket(int(math.ceil(self.horizon / DT)), 256)
+        return _bucket(int(math.ceil(self.horizon / DT)), CHUNK)
 
 
 def build_cell(trace: Trace, cluster: ClusterSpec, policy,
@@ -262,7 +268,9 @@ def build_cell(trace: Trace, cluster: ClusterSpec, policy,
     ``seed`` additionally drives a small per-job duration jitter standing
     in for the event engine's per-task lognormal draw."""
     lowered = lower_policy(policy)
-    jobs = trace.job_specs(cluster)
+    # job specs include HDFS block placement, the build's O(jobs x nodes)
+    with span("repro.surrogate.job_specs"):
+        jobs = trace.job_specs(cluster)
     n = len(jobs)
     if n == 0:
         raise ValueError("surrogate cell needs at least one job")
@@ -421,11 +429,15 @@ def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
 
     Returns ``kernel(packed) -> outputs`` where outputs are per-job
     ``finish`` times (``_INF`` = unfinished), accumulated local/remote
-    launch mass, and the latched-step count.  ``diag=True`` additionally
-    stacks per-step cluster aggregates (active jobs, queued mass, free
-    slots, launch totals, launch-weighted locality, crowding, latch) —
-    the observability hook calibration probes use.  Pure jnp: safe under
-    both direct call and ``vmap``."""
+    launch mass, the latched-step count, and ``chunks``: the int32 count
+    of ``CHUNK``-step chunks the early-exit loop ran (``n_steps // CHUNK``
+    on the ``diag`` scan).  Each stage of a step runs under a
+    ``jax.named_scope`` (``KERNEL_STAGES``), which names its operations in
+    the compiled executable (:func:`kernel_stages`).  ``diag=True``
+    additionally stacks per-step cluster aggregates (active jobs, queued
+    mass, free slots, launch totals, launch-weighted locality, crowding,
+    latch) — the observability hook calibration probes use.  Pure jnp:
+    safe under both direct call and ``vmap``."""
     import jax
     import jax.numpy as jnp
 
@@ -433,8 +445,9 @@ def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
     L = _RING
 
     def kernel(p):
-        order = jnp.argsort(p["prio_key"])
-        inv_order = jnp.argsort(order)
+        with jax.named_scope("setup"):
+            order = jnp.argsort(p["prio_key"])
+            inv_order = jnp.argsort(order)
         submit = p["submit"]
         pad_mask = p["pad_mask"]
         lag_ml = p["lag_ml"].astype(jnp.int32)
@@ -448,143 +461,157 @@ def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
         def step(carry, it):
             (pend_m, ring_m, pend_r, ring_r, park_s, park_x, finish,
              loc_acc, rem_acc, latch, lsteps) = carry
-            t = it.astype(jnp.float32) * dt
-            submitted = (submit <= t).astype(jnp.float32) * pad_mask
             # completions leave the ring first — they free slots this
             # step.  Ring maintenance is O(J) scatter/gather on the
             # maturing column; each ring pays exactly one full O(J·L)
             # reduction per step and every later sum is derived from it
             # arithmetically (the scan spends its time in these rows).
-            idx = jnp.mod(it, L)
-            ring_m = ring_m.at[:, idx].set(0.0)
-            ring_r = ring_r.at[:, idx].set(0.0)
-            # parked mass whose wait matures this step enters service: a
-            # successful park runs local, an expired one reads remote
-            mat_s = park_s[:, idx]
-            mat_x = park_x[:, idx]
-            park_s = park_s.at[:, idx].set(0.0)
-            park_x = park_x.at[:, idx].set(0.0)
-            inflight_m = jnp.sum(ring_m, axis=1)
-            inflight_r = jnp.sum(ring_r, axis=1)
-            waiting = jnp.sum(park_s, axis=1) + jnp.sum(park_x, axis=1)
-            map_left = pend_m + inflight_m + waiting + mat_s + mat_x
-            red_left = pend_r + inflight_r
-            map_open = submitted * (map_left > _EPS)
-            red_open = submitted * (map_left <= _EPS) * (red_left > _EPS)
+            with jax.named_scope("ring_drain"):
+                idx = jnp.mod(it, L)
+                ring_m = ring_m.at[:, idx].set(0.0)
+                ring_r = ring_r.at[:, idx].set(0.0)
+                # parked mass whose wait matures this step enters
+                # service: a successful park runs local, an expired one
+                # reads remote
+                mat_s = park_s[:, idx]
+                mat_x = park_x[:, idx]
+                park_s = park_s.at[:, idx].set(0.0)
+                park_x = park_x.at[:, idx].set(0.0)
+                inflight_m = jnp.sum(ring_m, axis=1)
+                inflight_r = jnp.sum(ring_r, axis=1)
+                waiting = jnp.sum(park_s, axis=1) + jnp.sum(park_x, axis=1)
             # latch entry/exit on beginning-of-step queue pressure
-            pending = jnp.sum(pend_m * submitted)
-            active = jnp.sum(submitted * ((map_left > _EPS)
-                                          | (red_left > _EPS)))
-            trip = ((pending >= p["pending_bar"])
-                    & (active >= p["active_bar"]))
-            latch = (p["overload"] > 0.5) & ((latch | trip) & (active > 0.5))
-            use_fair = use_fair_ordering | latch
-            park_on = (p["park"] > 0.5) & ~latch
-            chi_raw = active / p["machines"]
-            chi = jnp.clip(chi_raw, 0.0, 1.0)
+            with jax.named_scope("latch"):
+                t = it.astype(jnp.float32) * dt
+                submitted = (submit <= t).astype(jnp.float32) * pad_mask
+                map_left = pend_m + inflight_m + waiting + mat_s + mat_x
+                red_left = pend_r + inflight_r
+                map_open = submitted * (map_left > _EPS)
+                red_open = submitted * (map_left <= _EPS) * (red_left > _EPS)
+                pending = jnp.sum(pend_m * submitted)
+                active = jnp.sum(submitted * ((map_left > _EPS)
+                                              | (red_left > _EPS)))
+                trip = ((pending >= p["pending_bar"])
+                        & (active >= p["active_bar"]))
+                latch = (p["overload"] > 0.5) & ((latch | trip)
+                                                 & (active > 0.5))
+                use_fair = use_fair_ordering | latch
+                park_on = (p["park"] > 0.5) & ~latch
+                chi_raw = active / p["machines"]
+                chi = jnp.clip(chi_raw, 0.0, 1.0)
             # -- map demand ----------------------------------------------
             # a parked task donates its core to the reconfiguration pool,
             # where it is *held* for the donor wait — unavailable to the
             # scheduler.  That capacity holdback is the park tax the
             # oracle measures (diurnal proposed runs the map pool at
             # ~50% utilization through its overload phase).
-            free_m = jnp.maximum(
-                p["map_slots"] - jnp.sum(inflight_m) - jnp.sum(waiting),
-                0.0)
-            # two allocation rounds, after the event scheduler's
-            # demand/backfill phases: round 1 caps each job at its share
-            # of the pool (parked tasks count as in-flight against it),
-            # round 2 backfills leftover slots with any uncapped pending
-            # mass — so a heavy-tailed giant keeps freed slots busy,
-            # while a fleet of similar jobs that all parked together has
-            # nothing left to backfill with and the pool idles.
-            n_open = jnp.maximum(jnp.sum(map_open), 1.0)
-            share = p["map_slots"] / n_open
-            cap = jnp.maximum(share - waiting, 0.0)
-            offered = jnp.minimum(pend_m, cap) * map_open
-            launch1 = jnp.where(
-                use_fair,
-                _fair_waterfill(jnp, offered, free_m),
-                _priority_alloc(jnp, offered, free_m, order, inv_order))
-            spare = jnp.maximum(free_m - jnp.sum(launch1), 0.0)
-            off2 = jnp.maximum(pend_m - launch1, 0.0) * map_open
-            launch2 = jnp.where(
-                use_fair,
-                _fair_waterfill(jnp, off2, spare),
-                _priority_alloc(jnp, off2, spare, order, inv_order))
-            launch = launch1 + launch2
-            blocked = jnp.sum(waiting)
-            # baseline locality: the offer scan's effective placement
-            # draws per launch (constant — the event engine books ~the
-            # same locality for fair and fifo); delay scheduling's skipped
-            # offers multiply the draws
-            lf_base = 1.0 - jnp.exp(ell_exponent * LOCALITY_DRAWS
-                                    * log_miss)
-            launch_loc = launch * lf_base
-            rest = launch - launch_loc
-            # park outcome odds and waits, degraded by the active crowd
-            # (donor cores are co-located VMs' spare capacity)
-            wait_eff = jnp.minimum(
-                PARK_WAIT * (1.0 + PARK_WAIT_CROWD * chi), p["max_wait"])
-            p_succ = PARK_SUCCESS * jnp.maximum(
-                1.0 - PARK_CROWD_PENALTY * chi, 0.0)
-            ws = jnp.round(wait_eff / dt).astype(jnp.int32)
-            saturate = jnp.clip((chi_raw - SAT_LO) / SAT_WIDTH, 0.0, 1.0)
-            wx = jnp.minimum(jnp.round(
-                p["max_wait"] * (1.0 + REPARK_CROWD * saturate) / dt
-            ).astype(jnp.int32), L - 1)
-            # deadline-critical bypass (the event reconfigurator's own
-            # guard, verbatim): a job inside 3x the park patience of its
-            # absolute deadline skips parking and reads remote
-            # immediately — so a blown-deadline backlog stops donating
-            # its launches to the park queue.
-            crit = (p["dl_abs"] - t) <= 3.0 * p["max_wait"]
-            park_f = park_on.astype(jnp.float32) \
-                * (1.0 - crit.astype(jnp.float32))
-            f_psucc = rest * park_f * p_succ
-            f_pexp = rest * park_f * (1.0 - p_succ)
-            f_rem = rest * (1.0 - park_f)
-            # remote reads launched together contend on the fabric
-            rem_load = jnp.sum(f_rem + mat_x) / p["map_slots"]
-            delay_lag = jnp.round(
-                DELAY_REMOTE_WAIT * p["locality_delay"] / dt
-            ).astype(jnp.int32)
-            lag_mr_eff = jnp.minimum(
-                lag_mr + delay_lag + jnp.round(
-                    lag_mr.astype(jnp.float32) * NET_CONTENTION * rem_load
+            with jax.named_scope("map_alloc"):
+                free_m = jnp.maximum(
+                    p["map_slots"] - jnp.sum(inflight_m) - jnp.sum(waiting),
+                    0.0)
+                # two allocation rounds, after the event scheduler's
+                # demand/backfill phases: round 1 caps each job at its
+                # share of the pool (parked tasks count as in-flight
+                # against it), round 2 backfills leftover slots with any
+                # uncapped pending mass — so a heavy-tailed giant keeps
+                # freed slots busy, while a fleet of similar jobs that all
+                # parked together has nothing left to backfill with and
+                # the pool idles.
+                n_open = jnp.maximum(jnp.sum(map_open), 1.0)
+                share = p["map_slots"] / n_open
+                cap = jnp.maximum(share - waiting, 0.0)
+                offered = jnp.minimum(pend_m, cap) * map_open
+                launch1 = jnp.where(
+                    use_fair,
+                    _fair_waterfill(jnp, offered, free_m),
+                    _priority_alloc(jnp, offered, free_m, order, inv_order))
+                spare = jnp.maximum(free_m - jnp.sum(launch1), 0.0)
+                off2 = jnp.maximum(pend_m - launch1, 0.0) * map_open
+                launch2 = jnp.where(
+                    use_fair,
+                    _fair_waterfill(jnp, off2, spare),
+                    _priority_alloc(jnp, off2, spare, order, inv_order))
+                launch = launch1 + launch2
+                blocked = jnp.sum(waiting)
+                pend_m = jnp.maximum(pend_m - launch, 0.0)
+                pend_m = jnp.where(pend_m <= 0.01, 0.0, pend_m)
+            with jax.named_scope("park"):
+                # baseline locality: the offer scan's effective placement
+                # draws per launch (constant — the event engine books
+                # ~the same locality for fair and fifo); delay
+                # scheduling's skipped offers multiply the draws
+                lf_base = 1.0 - jnp.exp(ell_exponent * LOCALITY_DRAWS
+                                        * log_miss)
+                launch_loc = launch * lf_base
+                rest = launch - launch_loc
+                # park outcome odds and waits, degraded by the active
+                # crowd (donor cores are co-located VMs' spare capacity)
+                wait_eff = jnp.minimum(
+                    PARK_WAIT * (1.0 + PARK_WAIT_CROWD * chi), p["max_wait"])
+                p_succ = PARK_SUCCESS * jnp.maximum(
+                    1.0 - PARK_CROWD_PENALTY * chi, 0.0)
+                ws = jnp.round(wait_eff / dt).astype(jnp.int32)
+                saturate = jnp.clip((chi_raw - SAT_LO) / SAT_WIDTH, 0.0, 1.0)
+                wx = jnp.minimum(jnp.round(
+                    p["max_wait"] * (1.0 + REPARK_CROWD * saturate) / dt
                 ).astype(jnp.int32), L - 1)
+                # deadline-critical bypass (the event reconfigurator's own
+                # guard, verbatim): a job inside 3x the park patience of
+                # its absolute deadline skips parking and reads remote
+                # immediately — so a blown-deadline backlog stops donating
+                # its launches to the park queue.
+                crit = (p["dl_abs"] - t) <= 3.0 * p["max_wait"]
+                park_f = park_on.astype(jnp.float32) \
+                    * (1.0 - crit.astype(jnp.float32))
+                f_psucc = rest * park_f * p_succ
+                f_pexp = rest * park_f * (1.0 - p_succ)
+                f_rem = rest * (1.0 - park_f)
+                # remote reads launched together contend on the fabric
+                rem_load = jnp.sum(f_rem + mat_x) / p["map_slots"]
+                delay_lag = jnp.round(
+                    DELAY_REMOTE_WAIT * p["locality_delay"] / dt
+                ).astype(jnp.int32)
+                lag_mr_eff = jnp.minimum(
+                    lag_mr + delay_lag + jnp.round(
+                        lag_mr.astype(jnp.float32) * NET_CONTENTION
+                        * rem_load).astype(jnp.int32), L - 1)
+                loc_acc = loc_acc + launch_loc + f_psucc
+                rem_acc = rem_acc + f_rem + f_pexp
+                lf = (launch_loc + f_psucc) / jnp.maximum(launch, _EPS)
             rows = jnp.arange(n_jobs)
-            ring_m = ring_m.at[rows, jnp.mod(it + lag_ml, L)].add(
-                launch_loc + mat_s)
-            ring_m = ring_m.at[rows, jnp.mod(it + lag_mr_eff, L)].add(
-                f_rem + mat_x)
-            park_s = park_s.at[:, jnp.mod(it + ws, L)].add(f_psucc)
-            park_x = park_x.at[:, jnp.mod(it + wx, L)].add(f_pexp)
-            pend_m = jnp.maximum(pend_m - launch, 0.0)
-            pend_m = jnp.where(pend_m <= 0.01, 0.0, pend_m)
-            loc_acc = loc_acc + launch_loc + f_psucc
-            rem_acc = rem_acc + f_rem + f_pexp
-            lf = (launch_loc + f_psucc) / jnp.maximum(launch, _EPS)
+            with jax.named_scope("ring_scatter"):
+                ring_m = ring_m.at[rows, jnp.mod(it + lag_ml, L)].add(
+                    launch_loc + mat_s)
+                ring_m = ring_m.at[rows, jnp.mod(it + lag_mr_eff, L)].add(
+                    f_rem + mat_x)
+                park_s = park_s.at[:, jnp.mod(it + ws, L)].add(f_psucc)
+                park_x = park_x.at[:, jnp.mod(it + wx, L)].add(f_pexp)
             # -- reduce --------------------------------------------------
-            off_r = pend_r * red_open
-            free_r = jnp.maximum(p["red_slots"] - jnp.sum(inflight_r), 0.0)
-            launch_r = jnp.where(
-                use_fair,
-                _fair_waterfill(jnp, off_r, free_r),
-                _priority_alloc(jnp, off_r, free_r, order, inv_order))
-            ring_r = ring_r.at[rows, jnp.mod(it + lag_rr, L)].add(launch_r)
-            pend_r = jnp.maximum(pend_r - launch_r, 0.0)
-            pend_r = jnp.where(pend_r <= 0.01, 0.0, pend_r)
+            with jax.named_scope("reduce"):
+                off_r = pend_r * red_open
+                free_r = jnp.maximum(p["red_slots"] - jnp.sum(inflight_r),
+                                     0.0)
+                launch_r = jnp.where(
+                    use_fair,
+                    _fair_waterfill(jnp, off_r, free_r),
+                    _priority_alloc(jnp, off_r, free_r, order, inv_order))
+                # the reduce ring's scatter is ring maintenance too
+                with jax.named_scope("ring_scatter"):
+                    ring_r = ring_r.at[rows, jnp.mod(it + lag_rr, L)].add(
+                        launch_r)
+                pend_r = jnp.maximum(pend_r - launch_r, 0.0)
+                pend_r = jnp.where(pend_r <= 0.01, 0.0, pend_r)
             # -- completions ---------------------------------------------
             # post-launch remaining mass, derived from the pre-launch
             # reductions plus exactly what this step scattered in
-            map_left = pend_m + inflight_m + launch_loc + mat_s \
-                + f_rem + mat_x + waiting + f_psucc + f_pexp
-            red_left = pend_r + inflight_r + launch_r
-            done = (submitted > 0.5) & (map_left <= _EPS) \
-                & (red_left <= _EPS)
-            finish = jnp.where(done & (finish >= _INF), t + dt, finish)
-            lsteps = lsteps + latch.astype(jnp.float32)
+            with jax.named_scope("completion"):
+                map_left = pend_m + inflight_m + launch_loc + mat_s \
+                    + f_rem + mat_x + waiting + f_psucc + f_pexp
+                red_left = pend_r + inflight_r + launch_r
+                done = (submitted > 0.5) & (map_left <= _EPS) \
+                    & (red_left <= _EPS)
+                finish = jnp.where(done & (finish >= _INF), t + dt, finish)
+                lsteps = lsteps + latch.astype(jnp.float32)
             ys = None
             if diag:
                 lsum = jnp.maximum(jnp.sum(launch), _EPS)
@@ -613,17 +640,18 @@ def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
         if diag:
             its = jnp.arange(n_steps, dtype=jnp.int32)
             final, ys = jax.lax.scan(step, init, its)
+            chunks = jnp.asarray(n_steps // CHUNK, jnp.int32)
         else:
             # early exit at chunk granularity: once every real job has
             # finished, further steps are exact no-ops (no pending mass,
             # empty rings, latch released), so skipping them is
             # bit-identical to integrating the full horizon — the scan
             # just stops paying for the drain tail.
-            chunk = 256
-            n_chunks = max(n_steps // chunk, 1)
+            n_chunks = max(n_steps // CHUNK, 1)
 
             def unfinished(carry):
-                return jnp.any((carry[6] >= _INF) & (pad_mask > 0.5))
+                with jax.named_scope("early_exit"):
+                    return jnp.any((carry[6] >= _INF) & (pad_mask > 0.5))
 
             def cond(state):
                 carry, c = state
@@ -631,18 +659,20 @@ def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
 
             def body(state):
                 carry, c = state
-                its = c * chunk + jnp.arange(chunk, dtype=jnp.int32)
+                its = c * CHUNK + jnp.arange(CHUNK, dtype=jnp.int32)
                 carry, _ = jax.lax.scan(step, carry, its)
                 return (carry, c + 1)
 
-            final, _ = jax.lax.while_loop(
+            # under vmap each lane keeps its own count: a finished lane's
+            # carry, counter included, stops changing
+            final, chunks = jax.lax.while_loop(
                 cond, body, (init, jnp.asarray(0, jnp.int32)))
             ys = None
         (pend_m, _, pend_r, _, _, _, finish, loc_acc, rem_acc, _,
          lsteps) = final
         out = {"finish": finish, "local": loc_acc, "remote": rem_acc,
                "map_rem": pend_m, "red_rem": pend_r,
-               "latched_steps": lsteps}
+               "latched_steps": lsteps, "chunks": chunks}
         if diag:
             out["diag"] = ys
         return out
@@ -709,6 +739,136 @@ def _compiled(n_jobs: int, n_steps: int, batched: bool, diag: bool = False):
         fn = jax.jit(jax.vmap(kernel) if batched else kernel)
         _KERNEL_CACHE[key] = fn
     return fn
+
+
+#: the kernel's ``jax.named_scope`` stages: the prologue, the early exit's
+#: test, then a step's stages in order
+KERNEL_STAGES = ("setup", "early_exit", "ring_drain", "latch", "map_alloc",
+                 "park", "ring_scatter", "reduce", "completion")
+#: the stage of an operation whose ``op_name`` names none
+UNSCOPED = "unscoped"
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+) .*\{\s*$")
+_HLO_OP = re.compile(
+    r"^\s*(ROOT\s+)?%?([\w.\-]+) = (\w+\[([\d,]*)\])?.*? ([\w\-]+)\(")
+_OP_NAME = re.compile(r'metadata=\{[^}]*op_name="([^"]*)"')
+_CALLS = re.compile(r"\b(?:calls|to_apply)=%?([\w.\-]+)")
+_NAMES = re.compile(r"%([\w.\-]+)")
+#: control flow: its own time is loop plumbing, whatever its body holds
+_CONTROL = ("while", "conditional", "call")
+
+
+def _stage_of(op_name: str) -> str:
+    """The innermost stage in an ``op_name`` path.  Its last component is
+    the primitive, never a scope; a scope can show as ``vmap(<scope>)``."""
+    for part in reversed(op_name.split("/")[:-1]):
+        for token in reversed(re.split(r"[()]", part)):
+            if token in KERNEL_STAGES:
+                return token
+    return UNSCOPED
+
+
+@dataclass
+class _HloOp:
+    own: str                 # the stage its own ``op_name`` names
+    opcode: str
+    size: int                # elements of an array result, 0 for a tuple
+    called: List[str]        # computations it calls (fusion, to_apply)
+    operands: List[str]
+
+
+def hlo_stages(text: str) -> Dict[str, str]:
+    """``{instruction name: stage}`` of every instruction in an HLO text.
+
+    A fusion takes the stage of its fused computation's root (else of its
+    first instruction that has one); where that root is a tuple (a
+    multi-output fusion, which can join stages), the stage of its largest
+    element.  Any other instruction takes the
+    innermost stage its own ``op_name`` names.  XLA makes some
+    instructions without metadata (layout copies, a scatter it rewrites,
+    the windows of a decomposed cumsum); these take the stage of the
+    computation they call, else of their first operand that has one.
+    Control flow, and what has none of these, stays ``UNSCOPED``."""
+    ops: Dict[str, _HloOp] = {}
+    comps: Dict[str, List[str]] = {}      # computation -> [root, *members]
+    members: List[str] = [""]
+    for line in text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head and not line[0].isspace():
+            members = comps.setdefault(head.group(1), [""])
+            continue
+        op = _HLO_OP.match(line)
+        if op is None:
+            continue
+        name = op.group(2)
+        meta = _OP_NAME.search(line)
+        called = _CALLS.findall(line)
+        dims = op.group(4)
+        ops[name] = _HloOp(
+            own=_stage_of(meta.group(1)) if meta else UNSCOPED,
+            opcode=op.group(5),
+            size=0 if op.group(3) is None else math.prod(
+                int(d) for d in dims.split(",") if d),
+            called=called,
+            operands=[n for n in _NAMES.findall(line.split(" = ", 1)[1])
+                      if n not in called])
+        members.append(name)
+        if op.group(1):
+            members[0] = name
+    stages: Dict[str, str] = {}
+
+    def of_computation(comp: str) -> str:
+        root, *body = comps.get(comp, [""])
+        if root and ops[root].opcode == "tuple":
+            parts = sorted(ops[root].operands, key=lambda n: -ops[n].size)
+        else:
+            parts = [root] if root else []
+        for part in parts + body:
+            if stage(part) != UNSCOPED:
+                return stages[part]
+        return UNSCOPED
+
+    def first(refs, of) -> str:
+        for ref in refs:
+            found = of(ref)
+            if found != UNSCOPED:
+                return found
+        return UNSCOPED
+
+    def stage(name: str) -> str:
+        if name not in stages:
+            stages[name] = UNSCOPED            # a cycle guard
+            op = ops[name]
+            found = first(op.called, of_computation) \
+                if op.opcode == "fusion" else UNSCOPED
+            if found == UNSCOPED:
+                found = op.own
+            if found == UNSCOPED and op.opcode not in _CONTROL:
+                found = first(op.called, of_computation)
+                if found == UNSCOPED:
+                    found = first([r for r in op.operands if r in ops],
+                                  stage)
+            stages[name] = found
+        return stages[name]
+
+    for name in ops:
+        stage(name)
+    return stages
+
+
+def kernel_stages(n_jobs: int, n_steps: int, lanes: int) -> Dict[str, str]:
+    """``{HLO instruction name: stage}`` of the batched kernel's executable
+    for ``lanes`` cells in the (``n_jobs``, ``n_steps``) bucket.
+
+    Reads the compiled HLO text (:func:`hlo_stages`); a device trace names
+    its operations by those instruction names, with or without the ``%``.
+    The executable is the one ``run_batch`` runs, compiled through the
+    same caches."""
+    import jax
+    shapes = {k: jax.ShapeDtypeStruct(
+        (lanes, n_jobs) if k in _JOB_FIELDS else (lanes,), np.float32)
+        for k in _JOB_FIELDS + _SCALAR_FIELDS}
+    return hlo_stages(_compiled(n_jobs, n_steps, batched=True).lower(
+        shapes).compile().as_text())
 
 
 # ---------------------------------------------------------------------------
@@ -809,22 +969,35 @@ def run_batch(cells: Sequence[SurrogateCellInputs], *,
     input order and are bit-identical to ``run_cell`` on each cell alone,
     whatever the sub-batch cap (pinned by the fuzz suite)."""
     cap = _resolve_max_batch(max_batch)
-    groups: Dict[Tuple[int, int], List[int]] = {}
-    for i, cell in enumerate(cells):
-        groups.setdefault((cell.padded_jobs(), cell.n_steps()), []).append(i)
-    results: List[Optional[SurrogateResult]] = [None] * len(cells)
-    for (jp, ts), idxs in groups.items():
-        # sub-batch each bucket: per-cell results are independent of batch
-        # composition (pinned by the fuzz suite), and moderate batches keep
-        # the scan carry cache-resident — a single huge vmap thrashes
-        for lo in range(0, len(idxs), cap):
-            part = idxs[lo:lo + cap]
-            packed = [pack_cell(cells[i]) for i in part]
-            stacked = {k: np.stack([q[k] for q in packed])
-                       for k in packed[0]}
-            out = _compiled(jp, ts, batched=True)(stacked)
-            out = {k: np.asarray(v) for k, v in out.items()}
-            for row, i in enumerate(part):
-                results[i] = _unpack_result(
-                    cells[i], {k: v[row] for k, v in out.items()})
-    return results  # type: ignore[return-value]
+    with span("repro.surrogate.run_batch"):
+        groups: Dict[Tuple[int, int], List[int]] = {}
+        for i, cell in enumerate(cells):
+            groups.setdefault((cell.padded_jobs(), cell.n_steps()),
+                              []).append(i)
+        results: List[Optional[SurrogateResult]] = [None] * len(cells)
+        for (jp, ts), idxs in groups.items():
+            # sub-batch each bucket: per-cell results are independent of
+            # batch composition (pinned by the fuzz suite), and moderate
+            # batches keep the scan carry cache-resident — a single huge
+            # vmap thrashes
+            for lo in range(0, len(idxs), cap):
+                part = idxs[lo:lo + cap]
+                with span("repro.surrogate.pack"):
+                    packed = [pack_cell(cells[i]) for i in part]
+                    stacked = {k: np.stack([q[k] for q in packed])
+                               for k in packed[0]}
+                # the call copies the inputs to the device and enqueues
+                # the executable; a compile shows here, under its shape
+                with span("repro.surrogate.dispatch", lanes=len(part),
+                          jobs=jp, steps=ts):
+                    out = _compiled(jp, ts, batched=True)(stacked)
+                with span("repro.surrogate.fetch"):
+                    out = {k: np.asarray(v) for k, v in out.items()}
+                chunks = out["chunks"]
+                with span("repro.surrogate.unpack", lanes=len(part),
+                          jobs=jp, steps_run=int(chunks.max()) * CHUNK,
+                          lane_steps_run=int(chunks.sum()) * CHUNK):
+                    for row, i in enumerate(part):
+                        results[i] = _unpack_result(
+                            cells[i], {k: v[row] for k, v in out.items()})
+        return results  # type: ignore[return-value]

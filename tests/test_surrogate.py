@@ -257,3 +257,124 @@ def test_every_unsupported_registry_policy_raises():
         assert exc.value.label == name
     for name in supported:
         lower_policy(name)
+
+
+# ---------------------------------------------------------------------------
+# the loop counter and the stage map
+# ---------------------------------------------------------------------------
+
+def _stacked(cells):
+    import repro.simcluster.surrogate as sg
+    packed = [sg.pack_cell(c) for c in cells]
+    return packed, {k: np.stack([q[k] for q in packed]) for k in packed[0]}
+
+
+def test_chunks_count_each_lanes_own_loop():
+    """In a batch of cells that finish in different chunks, each lane
+    reports the chunks its own early exit ran: through the chunk that
+    holds its last finish, or every chunk for a cell that never finishes;
+    the unbatched kernel reports the same, the ``diag`` scan the whole
+    horizon."""
+    import dataclasses
+    import repro.simcluster.surrogate as sg
+    base = _cell(policy="fair")
+    horizon = 8 * sg.CHUNK * sg.DT
+    cells = [dataclasses.replace(base, submit=base.submit + shift,
+                                 dl_abs=base.dl_abs + shift, horizon=horizon)
+             for shift in (0.0, 3000.0, 7000.0, 2 * horizon)]
+    jp, ts = cells[0].padded_jobs(), cells[0].n_steps()
+    assert {(c.padded_jobs(), c.n_steps()) for c in cells} == {(jp, ts)}
+    packed, stacked = _stacked(cells)
+    out = sg._compiled(jp, ts, batched=True)(stacked)
+    chunks = np.asarray(out["chunks"])
+    assert chunks.dtype == np.int32
+    for lane, cell in enumerate(cells):
+        finish = np.asarray(out["finish"][lane][:cell.n_jobs])
+        if (finish < float(sg._INF)).all():
+            last_step = int(round(float(finish.max()) / sg.DT)) - 1
+            expected = last_step // sg.CHUNK + 1
+        else:
+            expected = ts // sg.CHUNK
+        assert chunks[lane] == expected, lane
+        alone = sg._compiled(jp, ts, batched=False)(packed[lane])
+        assert int(alone["chunks"]) == chunks[lane]
+    assert chunks.tolist() == [1, 3, 5, 8]
+    diag = sg._compiled(jp, ts, batched=False, diag=True)(packed[0])
+    assert int(diag["chunks"]) == ts // sg.CHUNK
+
+
+def test_results_ignore_the_chunk_counter():
+    """``_unpack_result`` reads the same result with or without the new
+    output, so records stay what they were."""
+    import repro.simcluster.surrogate as sg
+    cell = _cell(policy="proposed")
+    out = sg._compiled(cell.padded_jobs(), cell.n_steps(),
+                       batched=False)(sg.pack_cell(cell))
+    out = {k: np.asarray(v) for k, v in out.items()}
+    without = {k: v for k, v in out.items() if k != "chunks"}
+    assert _fingerprint(sg._unpack_result(cell, out)) == \
+        _fingerprint(sg._unpack_result(cell, without))
+
+
+def test_kernel_stages_name_the_ring_ops():
+    import repro.simcluster.surrogate as sg
+    stages = sg.kernel_stages(8, sg.CHUNK, 2)
+    found = set(stages.values())
+    assert {"ring_drain", "ring_scatter"} <= found
+    assert found <= set(sg.KERNEL_STAGES) | {sg.UNSCOPED}
+    assert all(not name.startswith("%") for name in stages)
+
+
+_HLO = """\
+HloModule m
+
+%fused_computation.1 (p0: f32[4,64]) -> (f32[], f32[4]) {
+  %p0 = f32[4,64]{1,0} parameter(0)
+  %c = f32[] constant(0)
+  %total = f32[] reduce(%p0, %c), dimensions={0,1}, to_apply=%add_r, metadata={op_name="jit(k)/vmap()/while/body/map_alloc/reduce_sum"}
+  %rows = f32[4]{0} reduce(%p0, %c), dimensions={1}, to_apply=%add_r, metadata={op_name="jit(k)/vmap()/while/body/ring_drain/reduce_sum"}
+  ROOT %t = (f32[], f32[4]{0}) tuple(%total, %rows)
+}
+
+%add_r (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %s = f32[] add(%a, %b), metadata={op_name="ring_scatter/add"}
+}
+
+%fused_computation.2 (q0: f32[256], q1: s32[4], q2: f32[4]) -> f32[256] {
+  %q0 = f32[256]{0} parameter(0)
+  %q1 = s32[4]{0} parameter(1)
+  %q2 = f32[4]{0} parameter(2)
+  ROOT %scatter.9 = f32[256]{0} scatter(%q0, %q1, %q2), to_apply=%add_r
+}
+
+ENTRY %main (x: f32[4,64], i: s32[4], u: f32[4]) -> f32[256] {
+  %x = f32[4,64]{1,0} parameter(0)
+  %i = s32[4]{0} parameter(1)
+  %u = f32[4]{0} parameter(2)
+  %sort.0 = f32[4,64]{1,0} sort(%x), dimensions={1}, metadata={op_name="jit(k)/vmap(setup)/jit(argsort)/sort"}
+  %fusion.1 = (f32[], f32[4]{0}) fusion(%sort.0), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(k)/vmap()/while/body/map_alloc/reduce_sum"}
+  %copy.3 = f32[4,64]{0,1} copy(%sort.0)
+  %bitcast.4 = f32[256]{0} bitcast(%copy.3)
+  %fusion.2 = f32[256]{0} fusion(%bitcast.4, %i, %u), kind=kCustom, calls=%fused_computation.2
+  %while.5 = f32[256]{0} while(%fusion.2), condition=%add_r, body=%add_r, metadata={op_name="jit(k)/vmap()/while"}
+  ROOT %copy.6 = f32[256]{0} copy(%while.5)
+}
+"""
+
+
+def test_hlo_stages_attribution_rules():
+    """A scope shows under ``vmap(...)``; a multi-output fusion takes its
+    largest output's stage; a rewritten scatter without metadata takes its
+    combiner's; a layout copy its operand's; control flow stays unscoped."""
+    import repro.simcluster.surrogate as sg
+    stages = sg.hlo_stages(_HLO)
+    assert stages["sort.0"] == "setup"
+    assert stages["fusion.1"] == "ring_drain"
+    assert stages["scatter.9"] == "ring_scatter"
+    assert stages["fusion.2"] == "ring_scatter"
+    assert stages["copy.3"] == "setup"
+    assert stages["while.5"] == sg.UNSCOPED
+    assert stages["copy.6"] == sg.UNSCOPED
+    assert stages["x"] == sg.UNSCOPED

@@ -70,3 +70,12 @@ def test_surrogate_kernel_compiles_for_v5e(one_chip, no_persistent_cache,
     compiled = fn.lower(_packed_shapes(n_jobs, batch, one_chip)).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < _TEMP_LIMIT, mem
+
+
+def test_v5e_executable_names_the_ring_stages(one_chip, no_persistent_cache):
+    """The chip's compiler keeps the stage scopes: the operations of the
+    v5e executable map to the kernel's ring stages."""
+    fn = sg._compiled(128, sg.CHUNK, batched=True)
+    compiled = fn.lower(_packed_shapes(128, 4, one_chip)).compile()
+    found = set(sg.hlo_stages(compiled.as_text()).values())
+    assert {"ring_drain", "ring_scatter", "map_alloc"} <= found

@@ -305,3 +305,52 @@ def test_cli_explain(tmp_path, capsys):
         main(["explain", "nope", "20x2"])
     with pytest.raises(SystemExit):
         main(["explain", "saturated", "13x7"])
+
+
+# -- wall-clock spans ---------------------------------------------------------
+
+def test_span_carries_the_request_it_is_opened_in(monkeypatch):
+    """Spans inside ``request()`` carry its number; requests count up and
+    nest; a span outside any request carries only its own arguments."""
+    import contextlib
+    import jax
+    from repro.core import tracing
+    seen = []
+
+    def annotation(name, **args):
+        seen.append((name, args))
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
+    with tracing.span("repro.x", lanes=2):
+        pass
+    with tracing.request() as a:
+        with tracing.span("repro.y"):
+            with tracing.request() as b:
+                tracing.span("repro.z", jobs=8)
+        tracing.span("repro.w")
+    assert b == a + 1
+    assert seen == [("repro.x", {"lanes": 2}), ("repro.y", {"request": a}),
+                    ("repro.z", {"jobs": 8, "request": b}),
+                    ("repro.w", {"request": a})]
+
+
+def test_cli_surrogate_profile_records_the_program_spans(tmp_path, capsys):
+    """``surrogate --profile DIR`` leaves a profiler trace whose host plane
+    holds the sweep's ``repro.surrogate.*`` spans."""
+    import glob
+    import jax
+    from repro.experiments.__main__ import main
+    assert main(["surrogate", "heavy_tail", "--seeds", "0",
+                 "--policies", "proposed", "--no-calibrate",
+                 "--cache", str(tmp_path / "cache"),
+                 "--profile", str(tmp_path / "prof")]) == 0
+    assert "[heavy_tail/20x2]" in capsys.readouterr().out
+    run = tmp_path / "prof" / "plugins" / "profile" / "*"
+    assert glob.glob(str(run / "perfetto_trace.json.gz"))
+    (path,) = glob.glob(str(run / "*.xplane.pb"))
+    names = {ev.name for plane in jax.profiler.ProfileData.from_file(
+        path).planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events}
+    assert {"repro.surrogate.sweep", "repro.surrogate.job_specs",
+            "repro.surrogate.unpack"} <= names
