@@ -331,33 +331,41 @@ def build_cell(trace: Trace, cluster: ClusterSpec, policy,
 
 #: names and order of the per-job tensor rows handed to the kernel
 _JOB_FIELDS = ("submit", "dl_abs", "map_mass0", "red_mass0", "lag_ml",
-               "lag_mr", "lag_rr", "c_over_n", "prio_key", "pad_mask")
+               "lag_mr", "lag_rr", "c_over_n", "pad_mask")
 #: per-cell scalar rows
 _SCALAR_FIELDS = ("map_slots", "red_slots", "machines", "remote_mult",
                   "ordering", "park", "overload", "locality_delay",
                   "max_wait", "pending_bar", "active_bar")
 
 
+def priority_order(cell: SurrogateCellInputs) -> np.ndarray:
+    """The cell's jobs in strict-priority order: FIFO by submission, every
+    other ordering by absolute deadline (fair ignores it).  The sort is
+    stable, so ties keep job index order, the event schedulers'
+    admission-seq tiebreak."""
+    if cell.policy.ordering == _ORDERING_CODES["fifo"]:
+        key = cell.submit
+    else:
+        key = cell.dl_abs
+    return np.argsort(key, kind="stable")
+
+
 def pack_cell(cell: SurrogateCellInputs) -> Dict[str, np.ndarray]:
-    """Pad one cell's arrays to its job bucket and stack the kernel inputs.
-    Padding jobs carry zero mass and a pad mask of 0 — they can never
-    activate, allocate, or finish."""
+    """Pad one cell's arrays to its job bucket and stack the kernel inputs,
+    jobs in :func:`priority_order`.  Padding jobs sit last, carry zero
+    mass and a pad mask of 0 — they can never activate, allocate, or
+    finish."""
     jp = cell.padded_jobs()
     n = cell.n_jobs
+    order = priority_order(cell)
 
     def pad(a: np.ndarray, fill: float = 0.0) -> np.ndarray:
         out = np.full(jp, fill, np.float32)
-        out[:n] = a.astype(np.float32)
+        out[:n] = a[order].astype(np.float32)
         return out
 
     pol = cell.policy
-    # priority key: FIFO sorts by submission, EDF by absolute deadline;
-    # fair ignores the key entirely.  jnp.argsort is stable, so ties
-    # resolve by job index — the event schedulers' admission-seq tiebreak.
-    if pol.ordering == _ORDERING_CODES["fifo"]:
-        prio = cell.submit.copy()
-    else:
-        prio = cell.dl_abs.copy()
+
     def lag(seconds: np.ndarray) -> np.ndarray:
         return np.clip(np.round(seconds / DT), 1, _RING - 1)
 
@@ -370,7 +378,6 @@ def pack_cell(cell: SurrogateCellInputs) -> Dict[str, np.ndarray]:
         "lag_mr": pad(lag(cell.map_t * cell.remote_mult), fill=1.0),
         "lag_rr": pad(lag(cell.red_t), fill=1.0),
         "c_over_n": pad(np.minimum(cell.c_repl / cell.n_nodes, 0.999)),
-        "prio_key": pad(prio, fill=_INF),
         "pad_mask": pad(np.ones(n, np.float32)),
     }
     scalars = {
@@ -406,14 +413,12 @@ def _fair_waterfill(jnp, demand, capacity):
     return alloc
 
 
-def _priority_alloc(jnp, demand, capacity, order, inv_order):
-    """Strict-priority waterfilling: jobs take their full demand in
-    ``order`` until capacity runs out.  ``order``/``inv_order`` are the
-    static priority permutation and its inverse."""
-    d_sorted = jnp.take(demand, order)
-    before = jnp.cumsum(d_sorted) - d_sorted
-    a_sorted = jnp.clip(capacity - before, 0.0, d_sorted)
-    return jnp.take(a_sorted, inv_order)
+def _priority_alloc(jnp, demand, capacity):
+    """Strict-priority waterfilling: jobs take their full demand in the
+    order they lie in (:func:`pack_cell` packs them in priority order)
+    until capacity runs out."""
+    before = jnp.cumsum(demand) - demand
+    return jnp.clip(capacity - before, 0.0, demand)
 
 
 def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
@@ -426,6 +431,9 @@ def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
     no closed-form drain law to mis-calibrate.  A launch's service lag is
     its locality outcome (local / remote / parked), so locality economics
     feed straight into capacity.
+
+    Jobs arrive in priority order (:func:`pack_cell`), so strict-priority
+    allocation fills them as they lie, and outputs come back in that order.
 
     Returns ``kernel(packed) -> outputs`` where outputs are per-job
     ``finish`` times (``_INF`` = unfinished), accumulated local/remote
@@ -446,17 +454,15 @@ def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
 
     def kernel(p):
         with jax.named_scope("setup"):
-            order = jnp.argsort(p["prio_key"])
-            inv_order = jnp.argsort(order)
-        submit = p["submit"]
-        pad_mask = p["pad_mask"]
-        lag_ml = p["lag_ml"].astype(jnp.int32)
-        lag_mr = p["lag_mr"].astype(jnp.int32)
-        lag_rr = p["lag_rr"].astype(jnp.int32)
-        log_miss = jnp.log1p(-p["c_over_n"])       # per-job, < 0
-        use_fair_ordering = p["ordering"] >= 1.5   # fair_deficit code
-        # delay scheduling: each skipped offer is more locality draws
-        ell_exponent = 1.0 + DELAY_BOOST * p["locality_delay"]
+            submit = p["submit"]
+            pad_mask = p["pad_mask"]
+            lag_ml = p["lag_ml"].astype(jnp.int32)
+            lag_mr = p["lag_mr"].astype(jnp.int32)
+            lag_rr = p["lag_rr"].astype(jnp.int32)
+            log_miss = jnp.log1p(-p["c_over_n"])       # per-job, < 0
+            use_fair_ordering = p["ordering"] >= 1.5   # fair_deficit code
+            # delay scheduling: each skipped offer is more locality draws
+            ell_exponent = 1.0 + DELAY_BOOST * p["locality_delay"]
 
         def step(carry, it):
             (pend_m, ring_m, pend_r, ring_r, park_s, park_x, finish,
@@ -524,13 +530,13 @@ def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
                 launch1 = jnp.where(
                     use_fair,
                     _fair_waterfill(jnp, offered, free_m),
-                    _priority_alloc(jnp, offered, free_m, order, inv_order))
+                    _priority_alloc(jnp, offered, free_m))
                 spare = jnp.maximum(free_m - jnp.sum(launch1), 0.0)
                 off2 = jnp.maximum(pend_m - launch1, 0.0) * map_open
                 launch2 = jnp.where(
                     use_fair,
                     _fair_waterfill(jnp, off2, spare),
-                    _priority_alloc(jnp, off2, spare, order, inv_order))
+                    _priority_alloc(jnp, off2, spare))
                 launch = launch1 + launch2
                 blocked = jnp.sum(waiting)
                 pend_m = jnp.maximum(pend_m - launch, 0.0)
@@ -594,7 +600,7 @@ def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
                 launch_r = jnp.where(
                     use_fair,
                     _fair_waterfill(jnp, off_r, free_r),
-                    _priority_alloc(jnp, off_r, free_r, order, inv_order))
+                    _priority_alloc(jnp, off_r, free_r))
                 # the reduce ring's scatter is ring maintenance too
                 with jax.named_scope("ring_scatter"):
                     ring_r = ring_r.at[rows, jnp.mod(it + lag_rr, L)].add(
@@ -913,9 +919,17 @@ class SurrogateResult:
 def _unpack_result(cell: SurrogateCellInputs, out: Dict[str, np.ndarray]
                    ) -> SurrogateResult:
     n = cell.n_jobs
-    finish = np.asarray(out["finish"][:n], np.float64)
-    local = np.asarray(out["local"][:n], np.float64)
-    remote = np.asarray(out["remote"][:n], np.float64)
+    order = priority_order(cell)
+
+    def in_trace_order(key: str) -> np.ndarray:
+        # the kernel's rows are in priority order: put them back
+        rows = np.empty(n, np.float64)
+        rows[order] = out[key][:n]
+        return rows
+
+    finish = in_trace_order("finish")
+    local = in_trace_order("local")
+    remote = in_trace_order("remote")
     latched = float(np.asarray(out["latched_steps"]))
     finished = finish < float(_INF)
     jobs: List[SurrogateJob] = []
@@ -982,7 +996,12 @@ def run_batch(cells: Sequence[SurrogateCellInputs], *,
             # vmap thrashes
             for lo in range(0, len(idxs), cap):
                 part = idxs[lo:lo + cap]
-                with span("repro.surrogate.pack"):
+                # lanes whose jobs the priority-order layout moves
+                reordered = sum(
+                    bool(np.any(priority_order(cells[i])
+                                != np.arange(cells[i].n_jobs)))
+                    for i in part)
+                with span("repro.surrogate.pack", reordered=reordered):
                     packed = [pack_cell(cells[i]) for i in part]
                     stacked = {k: np.stack([q[k] for q in packed])
                                for k in packed[0]}

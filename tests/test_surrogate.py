@@ -260,6 +260,116 @@ def test_every_unsupported_registry_policy_raises():
 
 
 # ---------------------------------------------------------------------------
+# jobs packed in priority order
+# ---------------------------------------------------------------------------
+
+def _gather_alloc(demand, capacity, key):
+    """Strict-priority allocation by permutation, as the kernel made it
+    before jobs came packed in priority order: gather the demand into key
+    order, fill, scatter the allocation back."""
+    import jax.numpy as jnp
+    order = np.argsort(key, kind="stable")
+    d = demand[order]
+    filled = np.asarray(jnp.clip(capacity - (jnp.cumsum(d) - d), 0.0, d))
+    alloc = np.empty_like(filled)
+    alloc[order] = filled
+    return alloc
+
+
+@pytest.mark.parametrize("case", ["random", "ties", "zero_demand",
+                                  "capacity_above_total", "whole_numbers"])
+def test_priority_alloc_on_sorted_rows_equals_the_gather_form(case):
+    import jax.numpy as jnp
+    import repro.simcluster.surrogate as sg
+    rng = np.random.default_rng(14)
+    n = 257
+    demand = rng.uniform(0.0, 7.0, n).astype(np.float32)
+    key = rng.permutation(n).astype(np.float32)
+    capacity = np.float32(0.4 * demand.sum())
+    if case == "ties":
+        key = rng.integers(0, 9, n).astype(np.float32)
+    elif case == "zero_demand":
+        demand[rng.random(n) < 0.4] = 0.0
+    elif case == "capacity_above_total":
+        capacity = np.float32(2.0 * demand.sum())
+    elif case == "whole_numbers":
+        demand = np.floor(demand)
+    order = np.argsort(key, kind="stable")
+    packed = np.asarray(sg._priority_alloc(jnp, demand[order], capacity))
+    alloc = np.empty_like(packed)
+    alloc[order] = packed
+    expected = _gather_alloc(demand, capacity, key)
+    assert alloc.tobytes() == expected.tobytes()
+    if case == "whole_numbers":   # every partial sum exact in float32
+        d = demand[order]
+        plain = np.clip(capacity - (np.cumsum(d) - d), 0.0, d)
+        assert packed.tobytes() == plain.astype(np.float32).tobytes()
+    if case == "capacity_above_total":
+        assert alloc.tobytes() == demand.tobytes()
+
+
+def _shuffled(cell, perm):
+    """The same cell with its jobs listed in ``perm``'s order."""
+    import dataclasses
+    arrays = ("submit", "dl_abs", "u_m", "v_r", "map_t", "red_t", "c_repl",
+              "deadlines_rel")
+    lists = ("job_ids", "workloads", "input_gb")
+    return dataclasses.replace(
+        cell, **{k: getattr(cell, k)[perm] for k in arrays},
+        **{k: [getattr(cell, k)[i] for i in perm] for k in lists})
+
+
+@pytest.mark.parametrize("policy", ["edf_nopark", "fifo", "proposed",
+                                    "fair"])
+def test_shuffled_cell_packs_and_answers_the_same(policy):
+    """Packing follows the priority key, not the jobs' listed order: a
+    cell whose jobs come shuffled packs to the same arrays and answers
+    the same, job by job, with its jobs in its own order."""
+    import repro.simcluster.surrogate as sg
+    cell = _cell(policy=policy, preset="heavy_tail")
+    key = cell.submit if policy == "fifo" else cell.dl_abs
+    assert len(set(key.tolist())) == cell.n_jobs     # distinct keys
+    perm = np.random.default_rng(3).permutation(cell.n_jobs)
+    shuffled = _shuffled(cell, perm)
+    a, b = sg.pack_cell(cell), sg.pack_cell(shuffled)
+    assert a.keys() == b.keys()
+    for k in a:
+        assert np.asarray(a[k]).tobytes() == np.asarray(b[k]).tobytes(), k
+    base, moved = run_cell(cell), run_cell(shuffled)
+    assert [j.job_id for j in moved.jobs] == shuffled.job_ids
+    fp_base, fp_moved = _fingerprint(base), _fingerprint(moved)
+    assert fp_moved[:4] == fp_base[:4]
+    assert fp_moved[5] == fp_base[5]
+    # the locality rate sums the same launches in another order
+    assert fp_moved[4] == pytest.approx(fp_base[4], rel=1e-12)
+    assert fp_moved[6] == tuple(fp_base[6][i] for i in perm)
+
+
+def test_priority_order_keeps_ties_in_index_order_and_padding_last():
+    import dataclasses
+    import jax.numpy as jnp
+    import repro.simcluster.surrogate as sg
+    cell = _cell(policy="edf_nopark", preset="heavy_tail")
+    # deadlines in a few tied groups, out of index order
+    dl_abs = (1000.0 * (np.arange(cell.n_jobs) % 5)[::-1]).astype(np.float32)
+    cell = dataclasses.replace(cell, dl_abs=dl_abs)
+    order = sg.priority_order(cell)
+    for i, j in zip(order[:-1], order[1:]):
+        assert dl_abs[i] < dl_abs[j] or (dl_abs[i] == dl_abs[j] and i < j)
+    packed = sg.pack_cell(cell)
+    n, jp = cell.n_jobs, cell.padded_jobs()
+    assert jp > n
+    assert packed["pad_mask"].tolist() == [1.0] * n + [0.0] * (jp - n)
+    assert (packed["dl_abs"][n:] == sg._INF).all()
+    assert packed["dl_abs"][:n].tobytes() == dl_abs[order].tobytes()
+    # the order a stable argsort of the padded key gives: padding last
+    padded_key = np.full(jp, sg._INF, np.float32)
+    padded_key[:n] = dl_abs
+    assert np.asarray(jnp.argsort(padded_key)).tolist() == \
+        order.tolist() + list(range(n, jp))
+
+
+# ---------------------------------------------------------------------------
 # the loop counter and the stage map
 # ---------------------------------------------------------------------------
 

@@ -79,3 +79,14 @@ def test_v5e_executable_names_the_ring_stages(one_chip, no_persistent_cache):
     compiled = fn.lower(_packed_shapes(128, 4, one_chip)).compile()
     found = set(sg.hlo_stages(compiled.as_text()).values())
     assert {"ring_drain", "ring_scatter", "map_alloc"} <= found
+
+
+def test_v5e_executable_has_no_priority_gathers(one_chip, no_persistent_cache):
+    """Jobs come packed in priority order, so strict-priority allocation
+    is a cumsum on the rows as they lie: no instruction of the v5e
+    executable gathers through ``jnp.take``."""
+    fn = sg._compiled(128, sg.CHUNK, batched=True)
+    text = fn.lower(_packed_shapes(128, 4, one_chip)).compile().as_text()
+    op_names = sg._OP_NAME.findall(text)
+    assert any("/map_alloc/" in name for name in op_names)
+    assert not [name for name in op_names if "jit(_take)/gather" in name]

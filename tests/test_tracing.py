@@ -335,6 +335,41 @@ def test_span_carries_the_request_it_is_opened_in(monkeypatch):
                     ("repro.w", {"request": a})]
 
 
+def test_pack_span_counts_the_lanes_packing_reorders(monkeypatch):
+    """``repro.surrogate.pack`` carries ``reordered``, the lanes whose
+    priority order moves jobs: none for FIFO on a submit-ordered trace,
+    otherwise each EDF lane whose deadlines are out of job order."""
+    import contextlib
+    import jax
+    import numpy as np
+    from repro.simcluster.surrogate import build_cell, run_batch
+    from repro.simcluster.traces import PRESETS, generate_trace
+    seen = []
+
+    def annotation(name, **args):
+        seen.append((name, args))
+        return contextlib.nullcontext()
+
+    def reordered(cells):
+        seen.clear()
+        run_batch(cells)
+        return [args["reordered"] for name, args in seen
+                if name == "repro.surrogate.pack"]
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
+    trace = generate_trace(PRESETS["mix_small"], seed=0)
+    cluster = ClusterSpec(num_machines=6, vms_per_machine=2, replication=1)
+    fifo = [build_cell(trace, cluster, "fifo", seed) for seed in (0, 1)]
+    assert all((np.diff(c.submit) >= 0).all() for c in fifo)
+    assert reordered(fifo) == [0]
+    edf = build_cell(trace, cluster, "edf_nopark", 0)
+    in_order = dataclasses.replace(edf, dl_abs=np.sort(edf.dl_abs))
+    lanes = [edf, in_order, edf]
+    out_of_order = sum(bool((np.diff(c.dl_abs) < 0).any()) for c in lanes)
+    assert out_of_order == 2
+    assert reordered(lanes) == [out_of_order]
+
+
 def test_cli_surrogate_profile_records_the_program_spans(tmp_path, capsys):
     """``surrogate --profile DIR`` leaves a profiler trace whose host plane
     holds the sweep's ``repro.surrogate.*`` spans."""
