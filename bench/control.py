@@ -1,11 +1,11 @@
 #!/usr/bin/env python3
 """The control of a cell's correctness check, at the cell's own size.
 
-Puts the plain reference computed in bfloat16 in the program's place and
-compares it, number by number, with the float32 reference on a sample of
-the cell's own request stream, as a run's check would.  A sound check
-calls it not correct.  It needs no chip (the reference is numpy); the
-benchmark's runs never run it.  Usage::
+Puts the cell's plain reference computed in bfloat16 in the program's
+place and compares it, number by number, with the float32 reference on a
+sample of the cell's own request stream, as a run's check would.  A sound
+check calls it not correct.  It needs no chip (the reference is numpy);
+the benchmark's runs never run it.  Usage::
 
     python3 bench/control.py --workload <cell> --seed <n> [--requests 4]
 
@@ -23,12 +23,12 @@ sys.path.insert(0, str(BENCH))
 import ml_dtypes  # noqa: E402
 
 from harness import check, program, registry  # noqa: E402
-from harness import reference as R  # noqa: E402
 from harness.traffic import Stream  # noqa: E402
 
 
 def control_numbers(workload, seed, requests, root=registry.ROOT):
     cell = registry.find_cell(workload, root)
+    R = cell.reference
     conf = dict(cell.config)
     conf["trace"] = program.trace_recipe(cell.config)
     stream = Stream(cell.traffic, workload, seed)

@@ -3,9 +3,10 @@
 Once the window has closed, a sample of the cells it answered, drawn from
 the run's seed, always holding the cell with the longest makespan and, where
 the traffic asks, cells from every slice of every request's batch, is
-answered again by the plain reference (``harness.reference``), which
-regenerates each cell from its deployment, policy and seed alone.  Each
-program answer is compared with the reference's, job by job:
+answered again by the cell's plain reference (the one its configuration
+names, ``harness.reference`` by default), which regenerates each cell from
+its deployment, policy and seed alone.  Each program answer is compared
+with the reference's, job by job:
 
 - ``mismatched_cells``: sampled cells whose record is missing or whose
   jobs (ids, count) differ from the reference's job stream;
@@ -27,7 +28,6 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from harness import reference as R
 from harness.traffic import derive
 
 NUMBERS = ("mismatched_cells", "finish_mismatch_jobs", "finish_max_abs_s",

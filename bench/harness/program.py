@@ -31,39 +31,49 @@ def trace_recipe(config: dict) -> dict:
     return recipe
 
 
-#: configuration ``cluster`` keys the program's ``ClusterSpec`` takes as
-#: they are, and those its ``AdaptiveConfig`` takes
-CLUSTER_KEYS = ("num_machines", "vms_per_machine", "base_map_slots",
-                "base_reduce_slots", "replication", "remote_penalty_scale")
+#: configuration ``cluster`` keys the program's ``AdaptiveConfig`` takes
 ADAPTIVE_KEYS = ("overload_pending_factor", "overload_active_factor")
 
 
-def cluster_spec(config: dict):
-    """The deployment as the program's ``ClusterSpec``.  Every key of the
-    configuration's ``cluster`` goes in; a key the program does not take is
-    an error, so the program and the reference never run different
-    deployments from one file."""
+def cluster_spec(config: dict, keys=None):
+    """The deployment as the program's ``ClusterSpec``.  ``keys`` are the
+    ``cluster`` keys the cell's reference models (``CLUSTER_KEYS``; the
+    default reference's where ``None``).  A key outside them, or one the
+    program does not take, is an error, so the program and the reference
+    never run different deployments from one file.  The adaptive keys go
+    to ``AdaptiveConfig``; every other key, nested groups such as
+    ``faults`` and ``serve`` among them, to ``ClusterSpec.from_dict``."""
+    import dataclasses
     from repro.core.types import AdaptiveConfig, ClusterSpec
+    if keys is None:
+        from harness.reference import CLUSTER_KEYS as keys
     c = dict(config["cluster"])
-    unknown = sorted(set(c) - set(CLUSTER_KEYS) - set(ADAPTIVE_KEYS))
+    unknown = sorted(set(c) - set(keys))
+    if unknown:
+        raise ValueError(f"cluster keys the reference does not model: "
+                         f"{unknown}")
+    adaptive = AdaptiveConfig(**{k: c.pop(k) for k in ADAPTIVE_KEYS if k in c})
+    taken = {f.name for f in dataclasses.fields(ClusterSpec)} - {"adaptive"}
+    unknown = sorted(set(c) - taken)
     if unknown:
         raise ValueError(f"cluster keys the program does not take: {unknown}")
-    adaptive = AdaptiveConfig(**{k: c.pop(k) for k in ADAPTIVE_KEYS if k in c})
-    return ClusterSpec(adaptive=adaptive, **c)
+    return ClusterSpec.from_dict(dict(c, adaptive=adaptive))
 
 
-def spec(config: dict, request, name: str):
-    """The request as the program's ``ExperimentSpec``.  A recipe trace is
-    one ``TraceConfig`` run at every seed of the request; a job-type trace
-    is one row set per seed (``harness.jobtypes``)."""
+def spec(cell, request):
+    """The request to the cell ``cell`` (a ``registry.CellSpec``) as the
+    program's ``ExperimentSpec``.  A recipe trace is one ``TraceConfig`` run
+    at every seed of the request; a job-type trace is one row set per seed
+    (``harness.jobtypes``), with the deadlines of the cell's reference."""
     from repro.experiments.runner import ExperimentSpec, TraceRef
     from repro.simcluster.traces import TraceConfig
     from harness import jobtypes
-    from harness.reference import deadline
+    config = cell.config
     recipe = trace_recipe(config)
     if jobtypes.is_job_types(recipe):
         traces = tuple(
-            TraceRef(rows=tuple(jobtypes.rows(recipe, s, deadline)),
+            TraceRef(rows=tuple(jobtypes.rows(recipe, s,
+                                              cell.reference.deadline)),
                      name=jobtypes.trace_name(recipe, s), seed=s)
             for s in request.seeds)
         seeds = (jobtypes.SIM_SEED,)
@@ -71,9 +81,9 @@ def spec(config: dict, request, name: str):
         traces = (TraceRef(config=TraceConfig.from_dict(recipe)),)
         seeds = tuple(request.seeds)
     return ExperimentSpec(
-        name=f"{name}-{request.index}", traces=traces,
-        clusters=(cluster_spec(config),), schedulers=tuple(request.policies),
-        seeds=seeds)
+        name=f"{cell.name}-{request.index}", traces=traces,
+        clusters=(cluster_spec(config, cell.reference.CLUSTER_KEYS),),
+        schedulers=tuple(request.policies), seeds=seeds)
 
 
 def request_cells(config: dict, request):

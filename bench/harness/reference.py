@@ -24,6 +24,10 @@ It integrates one cell at a time over its real jobs (no padding, no
 batching, no early-exit chunks) with plain ``numpy``.  Every array holds
 ``dtype``: float32 is the precision the model states; bfloat16 is the
 lower-precision control, which a sound comparison must reject.
+
+It is the reference of every configuration that names no other
+(``harness.registry``).  A reference exposes ``CLUSTER_KEYS``, ``lower``,
+``build``, ``answer`` and ``deadline``.
 """
 from __future__ import annotations
 
@@ -71,6 +75,12 @@ DELAY_BOOST = 0.35
 DELAY_REMOTE_WAIT = 2.0
 NET_CONTENTION = 1.25
 FAIR_ITERS = 8
+
+#: the configuration ``cluster`` keys this model reads, and so the only
+#: ones a deployment it checks may set
+CLUSTER_KEYS = ("num_machines", "vms_per_machine", "base_map_slots",
+                "base_reduce_slots", "replication", "remote_penalty_scale",
+                "overload_pending_factor", "overload_active_factor")
 
 #: policy name -> (ordering, park, overload, default params); ordering
 #: 0 = earliest deadline, 1 = submission order, 2 = fair share

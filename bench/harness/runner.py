@@ -10,8 +10,9 @@
    first request that ends at or after ``--seconds``.  With ``--trace 1``
    the profiler records the first ``trace_requests`` requests, and the
    window closes after them.
-3. Check: the device's memory peak is read, then the plain reference
-   answers a sample of the window's cells again (``harness.check``).
+3. Check: the device's memory peak is read, then the cell's plain
+   reference answers a sample of the window's cells again
+   (``harness.check``).
 4. Result: the metrics the cell reports, read by their own readers.
 """
 from __future__ import annotations
@@ -27,7 +28,6 @@ from pathlib import Path
 from typing import List, Optional
 
 from harness import check, program, registry
-from harness import reference as R
 from harness.device import (CompileCounter, NoAccelerator,
                             memory_peak_bytes, require_accelerator)
 from harness.traffic import Request, Stream
@@ -69,7 +69,7 @@ class Context:
 
 def _serve(cell, stream_request, cache_dir, traced) -> Served:
     import jax
-    exp = program.spec(cell.config, stream_request, cell.name)
+    exp = program.spec(cell, stream_request)
     start = time.perf_counter()
     try:
         if traced:
@@ -104,7 +104,8 @@ def _window(cell, stream, seconds, cache_dir, traced, limit
 
 
 def _checked(cell, served, seed) -> dict:
-    """Compare a sample of the window's cells with the plain reference."""
+    """Compare a sample of the window's cells with the cell's reference."""
+    R = cell.reference
     conf = dict(cell.config)
     conf["trace"] = program.trace_recipe(cell.config)
     cells, makespans, answers, groups = [], [], [], []

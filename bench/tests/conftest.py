@@ -17,16 +17,21 @@ for p in (str(ROOT / "src"), str(BENCH)):
 TINY_CELL = "probe.tiny_20x2"
 #: the grid's own traffic file and check rule on a tiny job-type deployment
 GRID_CELL = "grid.tinytypes_20x2"
+#: a deployment with a cluster key outside the default reference's, which
+#: brings a reference of its own that models it
+KEYED_CELL = "probe.keyed_20x2"
+KEYED_REFERENCE = "bench/references/keyed.py"
 
 
 @pytest.fixture
 def bench_root(tmp_path, monkeypatch):
-    """A checkout-shaped directory whose ``BENCHMARK.json`` names two new
+    """A checkout-shaped directory whose ``BENCHMARK.json`` names three new
     cells: one with its own configuration, traffic mix, limits and a new
     per-layer metric as files of their own, beside copies of the
-    benchmark's end-to-end readers; and one that runs the grid's traffic
-    file and check rule on a tiny job-type deployment.  No file of the
-    benchmark is edited."""
+    benchmark's end-to-end readers; one that runs the grid's traffic file
+    and check rule on a tiny job-type deployment; and one whose
+    configuration sets a cluster key (a quiet fault layer) that only the
+    reference it names models.  No file of the benchmark is edited."""
     # keep the persistent compile cache out of the checkout in tests
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
     # the rest of a run is driven on the CPU: skip only the look for a chip
@@ -58,6 +63,18 @@ def bench_root(tmp_path, monkeypatch):
     shutil.copy(BENCH / "traffic" / "grid.json", root / "bench" / "traffic")
     shutil.copy(BENCH / "checks" / "grid.fb2009_600x2.json",
                 root / "bench" / "checks" / f"{GRID_CELL}.json")
+    keyed = dict(config, reference=KEYED_REFERENCE)
+    keyed["cluster"] = dict(config["cluster"], faults={"enabled": True})
+    (root / "bench" / "configs" / "keyed_20x2.json").write_text(
+        json.dumps(keyed))
+    (root / KEYED_REFERENCE).parent.mkdir(parents=True)
+    (root / KEYED_REFERENCE).write_text(
+        '"""The default model; a quiet fault layer changes nothing."""\n'
+        "from harness.reference import CLUSTER_KEYS as DEFAULT_KEYS\n"
+        "from harness.reference import answer, build, deadline, lower\n"
+        'CLUSTER_KEYS = DEFAULT_KEYS + ("faults",)\n')
+    shutil.copy(BENCH / "checks" / "query.paper_20x2.json",
+                root / "bench" / "checks" / f"{KEYED_CELL}.json")
     (root / "bench" / "metrics" / "tiny_requests.py").write_text(
         "def read(ctx):\n    return float(len(ctx.served))\n")
     (root / "BENCHMARK.json").write_text(json.dumps({
@@ -69,11 +86,16 @@ def bench_root(tmp_path, monkeypatch):
                     {"name": "tinytypes_20x2", "source": "fixture",
                      "file": "bench/configs/tinytypes_20x2.json",
                      "reduced": ["num_jobs", "num_machines"],
-                     "why": "fixture"}],
+                     "why": "fixture"},
+                    {"name": "keyed_20x2", "source": "fixture",
+                     "file": "bench/configs/keyed_20x2.json",
+                     "reduced": ["num_jobs"], "why": "fixture"}],
         "workloads": [{"name": TINY_CELL, "config": "tiny_20x2",
                        "traffic": "tiny", "chips": 1, "why": "fixture"},
                       {"name": GRID_CELL, "config": "tinytypes_20x2",
-                       "traffic": "grid", "chips": 1, "why": "fixture"}],
+                       "traffic": "grid", "chips": 1, "why": "fixture"},
+                      {"name": KEYED_CELL, "config": "keyed_20x2",
+                       "traffic": "tiny", "chips": 1, "why": "fixture"}],
         "end_to_end": [
             {"name": "query_p50_s", "unit": "s", "better": "lower",
              "bound": 0.25, "source": "host_clock"},

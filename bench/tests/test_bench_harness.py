@@ -9,8 +9,10 @@ import time
 import numpy as np
 import pytest
 
-from conftest import BENCH, GRID_CELL, ROOT, TINY_CELL
-from harness import check, jobtypes, program, registry, runner
+import control
+from conftest import (BENCH, GRID_CELL, KEYED_CELL, KEYED_REFERENCE, ROOT,
+                      TINY_CELL)
+from harness import check, jobtypes, program, reference, registry, runner
 from harness.traffic import Stream
 
 SEED = 2**31 + 12345
@@ -36,6 +38,7 @@ def test_real_cells_found():
         cell = registry.find_cell(w["name"], ROOT)
         assert cell.end_to_end and cell.per_layer
         assert "setup_s" in [m.name for m in cell.end_to_end]
+        assert cell.reference is reference
 
 
 def test_fixture_run_is_correct(bench_root):
@@ -138,9 +141,8 @@ def test_job_type_seeds_share_jobs_and_gaps():
     order, so a seed never changes the work."""
     config = json.loads((BENCH / "configs" / "fb2009_600x2.json").read_text())
     trace = program.trace_recipe(config)
-    from harness.reference import deadline
-    a = jobtypes.rows(trace, 2**31 + 7, deadline)
-    b = jobtypes.rows(trace, 2**31 + 8, deadline)
+    a = jobtypes.rows(trace, 2**31 + 7, reference.deadline)
+    b = jobtypes.rows(trace, 2**31 + 8, reference.deadline)
     assert a != b
     assert sorted(r[:3] for r in a) == sorted(r[:3] for r in b)
     assert abs(a[-1][3] - b[-1][3]) < 0.01
@@ -157,6 +159,83 @@ def test_cluster_keys_all_reach_the_program():
     config["cluster"]["rack_count"] = 4
     with pytest.raises(ValueError, match="rack_count"):
         program.cluster_spec(config)
+
+
+def test_cluster_keys_a_reference_declares_reach_the_program():
+    """A key the reference models goes to the program, nested groups to
+    their config classes; a key the program does not take is refused even
+    where the reference declares it."""
+    from repro.core.types import FaultConfig
+    config = json.loads((BENCH / "configs" / "paper_20x2.json").read_text())
+    config["cluster"]["faults"] = {"enabled": True}
+    keys = reference.CLUSTER_KEYS + ("faults",)
+    spec = program.cluster_spec(config, keys)
+    assert spec.faults == FaultConfig(enabled=True)
+    assert spec.adaptive.overload_active_factor == 0.5
+    config["cluster"]["rack_count"] = 4
+    with pytest.raises(ValueError, match="program does not take.*rack_count"):
+        program.cluster_spec(config, keys + ("rack_count",))
+
+
+def test_keyed_cell_found_with_its_reference(bench_root):
+    """A configuration that names a reference gets that reference, with the
+    cluster keys it models; the others keep the default one."""
+    cell = registry.find_cell(KEYED_CELL, bench_root)
+    assert cell.reference is not reference
+    assert cell.reference.__file__ == str(bench_root / KEYED_REFERENCE)
+    assert set(cell.reference.CLUSTER_KEYS) == (
+        set(reference.CLUSTER_KEYS) | {"faults"})
+    assert registry.find_cell(TINY_CELL, bench_root).reference is reference
+    exp = program.spec(cell, Stream(cell.traffic, KEYED_CELL, SEED).request(0))
+    assert exp.clusters[0].faults.enabled
+
+
+def test_keyed_cell_run_is_correct(bench_root):
+    """The deployment with a key of its own, added as new files only, runs
+    end to end and its check passes."""
+    result = _run(bench_root, cell=KEYED_CELL)
+    assert result["correct"], result["compared"]
+    assert result["failed"] == 0 and result["attempted"] >= 1
+
+
+def test_control_runs_on_keyed_cell(bench_root):
+    numbers, limits = control.control_numbers(KEYED_CELL, SEED, 1,
+                                              root=bench_root)
+    assert set(numbers) == set(check.NUMBERS) == set(limits)
+    assert all(np.isfinite(v) for v in numbers.values())
+
+
+def test_keyed_cell_without_its_reference_is_refused(bench_root):
+    """The same configuration under the default reference, which does not
+    model its fault layer, is refused before any request is served."""
+    path = bench_root / "bench" / "configs" / "keyed_20x2.json"
+    config = json.loads(path.read_text())
+    del config["reference"]
+    path.write_text(json.dumps(config))
+    assert registry.find_cell(KEYED_CELL, bench_root).reference is reference
+    with pytest.raises(ValueError, match="faults"):
+        _run(bench_root, cell=KEYED_CELL)
+
+
+@pytest.mark.parametrize("named, text, error, match", [
+    (KEYED_REFERENCE,
+     "from harness.reference import CLUSTER_KEYS, build, deadline, lower\n",
+     AttributeError, r"keyed\.py.*answer"),
+    ("bench/references/keyed.txt", "", ValueError, "keyed.txt"),
+    ("src/keyed.py", "", ValueError, "src/keyed.py"),
+    ("bench/../keyed.py", "", ValueError, "keyed.py"),
+    ("bench/references/absent.py", "", FileNotFoundError, "absent.py"),
+])
+def test_reference_file_is_checked(bench_root, named, text, error, match):
+    """A reference that lacks part of the interface, or that is not a
+    Python file under ``bench/``, is refused with its name."""
+    (bench_root / KEYED_REFERENCE).write_text(text)
+    path = bench_root / "bench" / "configs" / "keyed_20x2.json"
+    config = json.loads(path.read_text())
+    config["reference"] = named
+    path.write_text(json.dumps(config))
+    with pytest.raises(error, match=match):
+        registry.find_cell(KEYED_CELL, bench_root)
 
 
 def test_cli_refuses_the_cpu():
