@@ -46,7 +46,9 @@ def control_numbers(workload, seed, requests, root=registry.ROOT):
         refs.append(R.answer(conf, policy, s, cell=built))
         answers.append(R.answer(conf, policy, s, dtype=ml_dtypes.bfloat16,
                                 cell=built))
-    return check.compare(answers, refs), check.limits(root, workload)
+    extras = check.extra(R)
+    return (check.compare(answers, refs, extras),
+            check.limits(root, workload, check.names(extras)))
 
 
 def main():

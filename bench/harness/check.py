@@ -13,7 +13,12 @@ with the reference's, job by job:
 - ``finish_mismatch_jobs``: jobs finished on one side only;
 - ``finish_max_abs_s`` / ``finish_mean_abs_s``: the largest and the mean
   gap in finish time over jobs finished on both sides;
-- ``locality_max_abs``: the largest gap in a cell's locality rate.
+- ``locality_max_abs``: the largest gap in a cell's locality rate;
+- ``<name>_max_abs`` for each name in the reference's ``EXTRA_NUMBERS``:
+  the largest gap in that number of a cell, which the reference's
+  ``answer`` returns and its ``record_numbers(rec)`` reads off the
+  program's ``RunRecord``.  A reference without ``EXTRA_NUMBERS`` adds
+  none.
 
 Each number has the limit the cell's file ``bench/checks/<cell>.json``
 gives it; the run is correct when every number is at or under its limit.
@@ -34,23 +39,39 @@ NUMBERS = ("mismatched_cells", "finish_mismatch_jobs", "finish_max_abs_s",
            "finish_mean_abs_s", "locality_max_abs")
 
 
-def limits(root: Path, workload: str) -> Dict[str, float]:
+def extra(reference) -> Tuple[str, ...]:
+    """The numbers the reference adds to its answer (``EXTRA_NUMBERS``)."""
+    return tuple(getattr(reference, "EXTRA_NUMBERS", ()))
+
+
+def names(extras: Sequence[str] = ()) -> Tuple[str, ...]:
+    """Every number compared where the reference adds ``extras``."""
+    return NUMBERS + tuple(f"{n}_max_abs" for n in extras)
+
+
+def limits(root: Path, workload: str,
+           compared: Sequence[str] = NUMBERS) -> Dict[str, float]:
+    """The limit of each number in ``compared`` (``names``)."""
     data = json.loads(
         (Path(root) / "bench" / "checks" / f"{workload}.json").read_text())
     got = data["limits"]
-    missing = [k for k in NUMBERS if k not in got]
+    missing = [k for k in compared if k not in got]
     if missing:
         raise KeyError(f"checks for {workload} lack limits for {missing}")
-    return {k: float(got[k]) for k in NUMBERS}
+    return {k: float(got[k]) for k in compared}
 
 
-def record_answer(rec) -> dict:
-    """A program ``RunRecord`` in the reference's answer shape."""
-    return {"job_ids": [j.job_id for j in rec.jobs],
-            "finish": np.array([np.nan if j.finish_time is None
-                                else j.finish_time for j in rec.jobs],
-                               np.float64),
-            "locality_rate": float(rec.locality_rate)}
+def record_answer(rec, reference) -> dict:
+    """A program ``RunRecord`` in the reference's answer shape, with the
+    numbers the reference reads off it (``record_numbers``)."""
+    out = {"job_ids": [j.job_id for j in rec.jobs],
+           "finish": np.array([np.nan if j.finish_time is None
+                               else j.finish_time for j in rec.jobs],
+                              np.float64),
+           "locality_rate": float(rec.locality_rate)}
+    if extra(reference):
+        out.update(reference.record_numbers(rec))
+    return out
 
 
 def sample(groups: Sequence[Sequence[int]], makespans: Sequence[float],
@@ -82,11 +103,12 @@ def sample(groups: Sequence[Sequence[int]], makespans: Sequence[float],
     return [longest] + rng.sample(rest, min(k - 1, len(rest)))
 
 
-def compare(answers: Sequence[dict], references: Sequence[dict]
-            ) -> Dict[str, float]:
+def compare(answers: Sequence[dict], references: Sequence[dict],
+            extras: Sequence[str] = ()) -> Dict[str, float]:
     """The numbers compared, over pairs of (answer, reference); an answer
-    of ``None`` is a cell the program never answered."""
-    out = dict.fromkeys(NUMBERS, 0.0)
+    of ``None`` is a cell the program never answered.  ``extras`` are the
+    reference's ``EXTRA_NUMBERS``, each read like the locality rate."""
+    out = dict.fromkeys(names(extras), 0.0)
     gaps = []
     for got, ref in zip(answers, references):
         if got is None or got["job_ids"] != ref["job_ids"]:
@@ -100,6 +122,10 @@ def compare(answers: Sequence[dict], references: Sequence[dict]
         out["locality_max_abs"] = max(
             out["locality_max_abs"],
             abs(got["locality_rate"] - ref["locality_rate"]))
+        for n in extras:
+            # np.maximum keeps a NaN gap, which the verdict then fails
+            out[f"{n}_max_abs"] = float(np.maximum(
+                out[f"{n}_max_abs"], abs(got[n] - ref[n])))
     gaps = np.concatenate(gaps) if gaps else np.zeros(0)
     if gaps.size:
         out["finish_max_abs_s"] = float(gaps.max())
@@ -108,10 +134,11 @@ def compare(answers: Sequence[dict], references: Sequence[dict]
 
 
 def verdict(numbers: Dict[str, float], limit: Dict[str, float]) -> bool:
+    """Every number that has a limit is at or under it."""
     return all(math.isfinite(numbers[k]) and numbers[k] <= limit[k]
-               for k in NUMBERS)
+               for k in limit)
 
 
 def report(numbers: Dict[str, float], limit: Dict[str, float]) -> dict:
     """The numbers beside their limits, as the result line carries them."""
-    return {k: {"value": numbers[k], "limit": limit[k]} for k in NUMBERS}
+    return {k: {"value": numbers[k], "limit": limit[k]} for k in limit}

@@ -27,7 +27,11 @@ lower-precision control, which a sound comparison must reject.
 
 It is the reference of every configuration that names no other
 (``harness.registry``).  A reference exposes ``CLUSTER_KEYS``, ``lower``,
-``build``, ``answer`` and ``deadline``.
+``build``, ``answer`` and ``deadline`` (``registry.REFERENCE_API``).  It
+may also expose ``EXTRA_NUMBERS``, names its ``answer`` returns besides
+these, with ``record_numbers(rec)``, which reads them off a program
+``RunRecord`` (``registry.OPTIONAL_API``); the check then compares each.
+This one adds none.
 """
 from __future__ import annotations
 

@@ -13,7 +13,9 @@ new files and entries, never an edit:
   models the deployment and decides the cell's ``correct``.  Without the
   key it is ``harness.reference``.  A reference declares the ``cluster``
   keys it models (``CLUSTER_KEYS``), so a deployment with a mechanism of its
-  own brings its reference and its keys as new files.
+  own brings its reference and its keys as new files.  It may also add
+  numbers to the cell's check (``EXTRA_NUMBERS`` with ``record_numbers``,
+  ``harness.check``), each with its limit in ``bench/checks/<cell>.json``.
 """
 from __future__ import annotations
 
@@ -54,6 +56,11 @@ class CellSpec:
 
 #: what every reference module exposes
 REFERENCE_API = ("CLUSTER_KEYS", "lower", "build", "answer", "deadline")
+#: what a reference may expose besides: ``EXTRA_NUMBERS``, a tuple of names
+#: that its ``answer`` also returns, and ``record_numbers(rec)``, which reads
+#: the same names off a program ``RunRecord``; each is compared as
+#: ``<name>_max_abs``.  Without them the check compares ``check.NUMBERS``.
+OPTIONAL_API = ("EXTRA_NUMBERS", "record_numbers")
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -94,6 +101,8 @@ def _reference(root: Path, config: dict) -> ModuleType:
         raise FileNotFoundError(f"reference {rel!r} has no file at {path}")
     module = _load(path, _module_name("bench_reference_", rel))
     missing = [a for a in REFERENCE_API if not hasattr(module, a)]
+    if getattr(module, "EXTRA_NUMBERS", ()):
+        missing += [a for a in OPTIONAL_API if not hasattr(module, a)]
     if missing:
         raise AttributeError(f"reference {rel!r} lacks {missing}")
     return module

@@ -117,18 +117,20 @@ def _checked(cell, served, seed) -> dict:
             rec = by_key.get((R.lower(policy), sd))
             groups[-1].append(len(cells))
             cells.append((policy, sd))
-            answers.append(None if rec is None else check.record_answer(rec))
+            answers.append(None if rec is None
+                           else check.record_answer(rec, R))
             makespans.append(-1.0 if rec is None else rec.makespan)
     picked = check.sample(groups, makespans, cell.traffic, cell.name, seed)
     refs = [R.answer(conf, cells[i][0], cells[i][1]) for i in picked]
-    return check.compare([answers[i] for i in picked], refs)
+    return check.compare([answers[i] for i in picked], refs, check.extra(R))
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool,
         t0: float, root: Path = registry.ROOT) -> dict:
     """One run of the cell ``workload``; returns the result object."""
     cell = registry.find_cell(workload, root)
-    limit = check.limits(root, workload)
+    limit = check.limits(root, workload,
+                         check.names(check.extra(cell.reference)))
     device = require_accelerator(cell.chips)
     from repro.simcluster.surrogate import use_compile_cache
     use_compile_cache()

@@ -5,13 +5,14 @@ import os
 import subprocess
 import sys
 import time
+from pathlib import Path
 
 import numpy as np
 import pytest
 
 import control
-from conftest import (BENCH, GRID_CELL, KEYED_CELL, KEYED_REFERENCE, ROOT,
-                      TINY_CELL)
+from conftest import (BENCH, GRID_CELL, KEYED_CELL, KEYED_REFERENCE,
+                      PROBE_CELL, PROBE_EXTRA, ROOT, TINY_CELL)
 from harness import check, jobtypes, program, reference, registry, runner
 from harness.traffic import Stream
 
@@ -32,13 +33,23 @@ def test_cell_found_by_name_without_edits(bench_root):
     assert [m.name for m in cell.per_layer] == ["tiny_requests"]
 
 
-def test_real_cells_found():
-    bench = registry.load_benchmark(ROOT)
+def test_real_cells_found(benchmark_root):
+    """Every cell of the benchmark, and of the benchmark with a deployment
+    added as new files and entries, is found with its metrics and with the
+    reference its configuration names (the default where it names none)."""
+    bench = registry.load_benchmark(benchmark_root)
     for w in bench["workloads"]:
-        cell = registry.find_cell(w["name"], ROOT)
+        cell = registry.find_cell(w["name"], benchmark_root)
         assert cell.end_to_end and cell.per_layer
         assert "setup_s" in [m.name for m in cell.end_to_end]
-        assert cell.reference is reference
+        named = cell.config.get("reference")
+        if named is None:
+            assert cell.reference is reference
+        else:
+            assert (Path(cell.reference.__file__).resolve()
+                    == (benchmark_root / named).resolve())
+            assert all(hasattr(cell.reference, a)
+                       for a in registry.REFERENCE_API)
 
 
 def test_fixture_run_is_correct(bench_root):
@@ -172,9 +183,12 @@ def test_cluster_keys_a_reference_declares_reach_the_program():
     spec = program.cluster_spec(config, keys)
     assert spec.faults == FaultConfig(enabled=True)
     assert spec.adaptive.overload_active_factor == 0.5
-    config["cluster"]["rack_count"] = 4
-    with pytest.raises(ValueError, match="program does not take.*rack_count"):
-        program.cluster_spec(config, keys + ("rack_count",))
+    # a key no program takes: a rack key would stop being one once a
+    # deployment with racks brings it to ClusterSpec
+    config["cluster"]["not_a_cluster_key"] = 4
+    with pytest.raises(ValueError,
+                       match="program does not take.*not_a_cluster_key"):
+        program.cluster_spec(config, keys + ("not_a_cluster_key",))
 
 
 def test_keyed_cell_found_with_its_reference(bench_root):
@@ -205,6 +219,77 @@ def test_control_runs_on_keyed_cell(bench_root):
     assert all(np.isfinite(v) for v in numbers.values())
 
 
+def test_extended_cell_run_is_correct(extended_root):
+    """The deployment added to the real benchmark as new files and entries
+    runs end to end on the CPU, reports the grid's metrics, and its check
+    compares the number its reference adds."""
+    result = _run(extended_root, cell=PROBE_CELL)
+    assert result["correct"], result["compared"]
+    assert result["failed"] == 0 and result["attempted"] >= 1
+    assert set(result["metrics"]) == {"cells_per_s", "setup_s"}
+    assert tuple(result["compared"]) == check.names(PROBE_EXTRA)
+    assert result["compared"]["makespan_max_abs"]["limit"] == 30
+
+
+def test_extended_cell_broken_extra_number_is_not_correct(extended_root,
+                                                          monkeypatch):
+    """The program's makespan read a minute late is caught by the extra
+    number alone: every other number still agrees."""
+    find = registry.find_cell
+
+    def broken(workload, root):
+        cell = find(workload, root)
+        read = cell.reference.record_numbers
+        monkeypatch.setattr(
+            cell.reference, "record_numbers",
+            lambda rec: {k: v + 60.0 for k, v in read(rec).items()})
+        return cell
+
+    monkeypatch.setattr(registry, "find_cell", broken)
+    result = _run(extended_root, cell=PROBE_CELL)
+    assert result["correct"] is False
+    compared = result["compared"]
+    assert (compared["makespan_max_abs"]["value"]
+            > compared["makespan_max_abs"]["limit"])
+    assert all(e["value"] <= e["limit"] for k, e in compared.items()
+               if k != "makespan_max_abs"), compared
+
+
+def test_extra_number_needs_its_limit(extended_root):
+    """A checks file without a limit for the reference's extra number is
+    refused before any request is served."""
+    path = extended_root / "bench" / "checks" / f"{PROBE_CELL}.json"
+    checks = json.loads(path.read_text())
+    del checks["limits"]["makespan_max_abs"]
+    path.write_text(json.dumps(checks))
+    with pytest.raises(KeyError, match="makespan_max_abs"):
+        _run(extended_root, cell=PROBE_CELL)
+
+
+def test_control_compares_extra_numbers(extended_root):
+    numbers, limits = control.control_numbers(PROBE_CELL, SEED, 1,
+                                              root=extended_root)
+    assert tuple(numbers) == tuple(limits) == check.names(PROBE_EXTRA)
+    assert all(np.isfinite(v) for v in numbers.values())
+
+
+@pytest.mark.parametrize("gap, correct", [(0.0, True), (30.0, True),
+                                          (30.5, False), (np.nan, False)])
+def test_extra_number_gap_is_compared(gap, correct):
+    """An extra number's gap is its largest over the cells, beside the
+    default numbers, and a NaN gap is never correct."""
+    ref = {"job_ids": ["a"], "finish": np.array([6.0]),
+           "locality_rate": 0.5, "makespan": 6.0}
+    got = dict(ref, makespan=6.0 + gap)
+    numbers = check.compare([ref, got], [ref, ref], PROBE_EXTRA)
+    assert tuple(numbers) == check.names(PROBE_EXTRA)
+    assert numbers["mismatched_cells"] == 0
+    limits = dict.fromkeys(check.NUMBERS, 0.0)
+    limits["makespan_max_abs"] = 30.0
+    assert check.verdict(numbers, limits) is correct
+    assert check.names() == check.NUMBERS
+
+
 def test_keyed_cell_without_its_reference_is_refused(bench_root):
     """The same configuration under the default reference, which does not
     model its fault layer, is refused before any request is served."""
@@ -225,6 +310,9 @@ def test_keyed_cell_without_its_reference_is_refused(bench_root):
     ("src/keyed.py", "", ValueError, "src/keyed.py"),
     ("bench/../keyed.py", "", ValueError, "keyed.py"),
     ("bench/references/absent.py", "", FileNotFoundError, "absent.py"),
+    (KEYED_REFERENCE,
+     "from harness.reference import *\nEXTRA_NUMBERS = ('makespan',)\n",
+     AttributeError, r"keyed\.py.*record_numbers"),
 ])
 def test_reference_file_is_checked(bench_root, named, text, error, match):
     """A reference that lacks part of the interface, or that is not a
@@ -251,12 +339,13 @@ def test_cli_refuses_the_cpu():
     assert "no accelerator" in proc.stderr
 
 
-def test_benchmark_json_names_existing_files():
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+def test_benchmark_json_names_existing_files(benchmark_root):
+    bench = json.loads((benchmark_root / "BENCHMARK.json").read_text())
+    files = benchmark_root / "bench"
     for c in bench["configs"]:
-        assert (ROOT / c["file"]).is_file()
+        assert (benchmark_root / c["file"]).is_file()
     for w in bench["workloads"]:
-        assert (BENCH / "traffic" / f"{w['traffic']}.json").is_file()
-        assert (BENCH / "checks" / f"{w['name']}.json").is_file()
+        assert (files / "traffic" / f"{w['traffic']}.json").is_file()
+        assert (files / "checks" / f"{w['name']}.json").is_file()
     for m in bench["end_to_end"] + bench["per_layer"]:
-        assert (BENCH / "metrics" / f"{m['name']}.py").is_file()
+        assert (files / "metrics" / f"{m['name']}.py").is_file()
