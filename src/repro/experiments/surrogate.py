@@ -140,9 +140,9 @@ def build_inputs(cells: Sequence[Cell]
                 with span("repro.surrogate.resolve"):
                     resolved[key] = cell.trace.resolve(cell.seed)
         traces = [resolved[(id(cell.trace), cell.seed)] for cell in cells]
-        # the expensive per-job compilation (block placements, jitter) is
-        # policy-independent: build once per (trace, seed, cluster) and
-        # swap only the lowered policy across the grid's policy columns
+        # the per-job compilation (task counts, jitter) is policy-
+        # independent: build once per (trace, seed, cluster) and swap only
+        # the lowered policy across the grid's policy columns
         base: Dict[Tuple[int, int, int], SurrogateCellInputs] = {}
         inputs = []
         for cell, trace in zip(cells, traces):

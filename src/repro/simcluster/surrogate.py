@@ -64,6 +64,8 @@ from repro.core.policies import PolicySpec
 from repro.core.tracing import span
 from repro.core.types import AdaptiveConfig, ClusterSpec
 from repro.simcluster.traces import Trace, _stable_seed
+from repro.simcluster.workloads import (WORKLOADS, distinct_holders,
+                                        n_map_tasks, task_counts)
 
 #: engine identity stamped into cache descriptors and bench entries.  The
 #: event engine's cells carry no ``engine`` key at all, so every surrogate
@@ -263,14 +265,20 @@ def build_cell(trace: Trace, cluster: ClusterSpec, policy,
     """Compile one (trace, cluster, policy) cell to surrogate inputs.
 
     Uses the *actual* trace jobs — submit times, task counts, profiles,
-    deadlines and the per-seed block placements — so the surrogate shares
-    every input the event engine sees and approximates only the dynamics.
-    ``seed`` additionally drives a small per-job duration jitter standing
-    in for the event engine's per-task lognormal draw."""
+    deadlines and the replica count HDFS placement gives each block — so
+    the surrogate shares every input the event engine sees and
+    approximates only the dynamics.  ``seed`` additionally drives a small
+    per-job duration jitter standing in for the event engine's per-task
+    lognormal draw."""
     lowered = lower_policy(policy)
-    # job specs include HDFS block placement, the build's O(jobs x nodes)
-    with span("repro.surrogate.job_specs"):
-        jobs = trace.job_specs(cluster)
+    jobs = trace.jobs
+    # rows straight from the trace: the fluid model reads a job's blocks
+    # only through their distinct holders, a constant of the cluster
+    # (``distinct_holders``), so no block is placed
+    with span("repro.surrogate.job_specs",
+              blocks=sum(n_map_tasks(j.input_gb) for j in jobs)):
+        profiles = [WORKLOADS[j.workload] for j in jobs]
+        counts = [task_counts(j.workload, j.input_gb) for j in jobs]
     n = len(jobs)
     if n == 0:
         raise ValueError("surrogate cell needs at least one job")
@@ -278,30 +286,24 @@ def build_cell(trace: Trace, cluster: ClusterSpec, policy,
         _stable_seed("surrogate-jitter", trace.name, trace.seed, seed))
     submit = np.array([j.submit_time for j in jobs], np.float32)
     dl_rel = np.array([j.deadline for j in jobs], np.float32)
-    u_m = np.array([j.u_m for j in jobs], np.float32)
-    v_r = np.array([j.v_r for j in jobs], np.float32)
+    u_m = np.array([um for um, _ in counts], np.float32)
+    v_r = np.array([vr for _, vr in counts], np.float32)
     # per-job mean durations; the phase mean over u_m iid task draws
     # concentrates ∝ 1/sqrt(u_m), which the jitter std reproduces
     map_t = np.empty(n, np.float32)
     red_t = np.empty(n, np.float32)
-    c_repl = np.empty(n, np.float32)
-    for i, j in enumerate(jobs):
-        prof = j.profile
+    for i, (prof, (um, vr)) in enumerate(zip(profiles, counts)):
         cv = getattr(prof, "time_cv", 0.08)
         z_m, z_r = rng.standard_normal(2)
-        jitter_m = math.exp(cv * z_m / math.sqrt(max(j.u_m, 1)))
-        jitter_r = math.exp(cv * z_r / math.sqrt(max(j.v_r, 1)))
+        jitter_m = math.exp(cv * z_m / math.sqrt(max(um, 1)))
+        jitter_r = math.exp(cv * z_r / math.sqrt(max(vr, 1)))
         map_t[i] = prof.map_time * TAIL_INFLATION * jitter_m
-        red_t[i] = ((prof.reduce_time + j.u_m * prof.shuffle_time_per_pair)
+        red_t[i] = ((prof.reduce_time + um * prof.shuffle_time_per_pair)
                     * TAIL_INFLATION * jitter_r)
-        if j.block_placement:
-            c_repl[i] = float(np.mean(
-                [len(set(p)) for p in j.block_placement[:j.u_m]]))
-        else:
-            c_repl[i] = float(min(cluster.replication, cluster.num_nodes))
+    c_repl = np.full(n, float(distinct_holders(cluster)), np.float32)
     # remote penalty is profile-uniform today (1.0); keep the first job's
     # profile as the cell's fabric calibration like the event engine does
-    rp = jobs[0].profile.remote_penalty
+    rp = profiles[0].remote_penalty
     remote_mult = 1.0 + rp * cluster.remote_penalty_scale
     map_slots = float(cluster.num_nodes * cluster.base_map_slots)
     red_slots = float(cluster.num_nodes * cluster.base_reduce_slots)
@@ -320,8 +322,8 @@ def build_cell(trace: Trace, cluster: ClusterSpec, policy,
         overload_active_factor=adaptive.overload_active_factor,
         horizon=horizon,
         job_ids=[j.job_id for j in jobs],
-        workloads=[j.profile.name for j in jobs],
-        input_gb=[j.input_size_gb for j in jobs],
+        workloads=[p.name for p in profiles],
+        input_gb=[j.input_gb for j in jobs],
         deadlines_rel=dl_rel)
 
 

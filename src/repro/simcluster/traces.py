@@ -39,8 +39,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 from repro.core.types import ClusterSpec, JobSpec
 from repro.simcluster.workloads import (PAPER_SKEW, PAPER_TABLE2_ROWS,
                                         WORKLOADS, default_deadline,
-                                        n_map_tasks, n_reduce_tasks,
-                                        place_blocks)
+                                        place_blocks, task_counts)
 
 TRACE_FORMAT = "repro-trace/v1"
 
@@ -227,12 +226,12 @@ class TraceJob:
 
     def to_job_spec(self, spec: ClusterSpec) -> JobSpec:
         rng = random.Random(self.placement_seed)
-        u_m = n_map_tasks(self.input_gb)
+        u_m, v_r = task_counts(self.workload, self.input_gb)
         return JobSpec(
             job_id=self.job_id,
             profile=WORKLOADS[self.workload],
             u_m=u_m,
-            v_r=n_reduce_tasks(self.workload, self.input_gb),
+            v_r=v_r,
             deadline=self.deadline,
             submit_time=self.submit_time,
             input_size_gb=self.input_gb,

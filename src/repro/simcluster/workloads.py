@@ -64,6 +64,18 @@ def n_reduce_tasks(workload: str, input_gb: float) -> int:
     return max(1, int(round(n_map_tasks(input_gb) * _REDUCE_FRACTION[workload])))
 
 
+def task_counts(workload: str, input_gb: float) -> Tuple[int, int]:
+    """``(u_m, v_r)``: a job's map tasks (one per block) and reduce tasks."""
+    return n_map_tasks(input_gb), n_reduce_tasks(workload, input_gb)
+
+
+def distinct_holders(spec: ClusterSpec,
+                     replication: Optional[int] = None) -> int:
+    """Distinct VMs holding each block that :func:`place_blocks` places:
+    every block, on the uniform and the skewed path alike."""
+    return min(replication or spec.replication, spec.num_nodes)
+
+
 def place_blocks(u_m: int, spec: ClusterSpec, rng: random.Random,
                  replication: Optional[int] = None,
                  skew: float = 0.0) -> List[Tuple[int, ...]]:
@@ -72,11 +84,14 @@ def place_blocks(u_m: int, spec: ClusterSpec, rng: random.Random,
     ``skew`` > 0 draws the primary machine from a power-law (weights
     (i+1)^-skew) — the hot/cold imbalance of real small virtual clusters
     (datanodes filling up, VM images co-placed) that the paper's
-    reconfiguration mechanism targets.  0 = uniform."""
-    r = replication or spec.replication
+    reconfiguration mechanism targets.  0 = uniform.
+
+    Invariant: each block has exactly ``distinct_holders(spec,
+    replication)`` distinct holders, which is all the surrogate reads."""
+    k = distinct_holders(spec, replication)
     nodes = list(range(spec.num_nodes))
     if skew <= 0:
-        return [tuple(rng.sample(nodes, min(r, len(nodes)))) for _ in range(u_m)]
+        return [tuple(rng.sample(nodes, k)) for _ in range(u_m)]
     # VM-level power-law skew with a per-job permutation of VM hotness:
     # VMs sharing a machine end up with *different* local demand, which is
     # exactly the imbalance Algorithm 1's intra-machine core transfer targets
@@ -87,7 +102,7 @@ def place_blocks(u_m: int, spec: ClusterSpec, rng: random.Random,
     out = []
     for _ in range(u_m):
         placed: List[int] = []
-        while len(placed) < min(r, len(nodes)):
+        while len(placed) < k:
             vm = perm[rng.choices(range(len(perm)), weights=weights)[0]]
             if vm not in placed:
                 placed.append(vm)
@@ -98,12 +113,12 @@ def place_blocks(u_m: int, spec: ClusterSpec, rng: random.Random,
 def make_job(job_id: str, workload: str, input_gb: float, deadline: float,
              spec: ClusterSpec, rng: random.Random,
              submit_time: float = 0.0, skew: float = 0.0) -> JobSpec:
-    u_m = n_map_tasks(input_gb)
+    u_m, v_r = task_counts(workload, input_gb)
     return JobSpec(
         job_id=job_id,
         profile=WORKLOADS[workload],
         u_m=u_m,
-        v_r=n_reduce_tasks(workload, input_gb),
+        v_r=v_r,
         deadline=deadline,
         submit_time=submit_time,
         input_size_gb=input_gb,

@@ -6,24 +6,29 @@ The wall is the contract behind ``CALIBRATED``: for every allowlisted
 fall inside the event oracle's 95% paired-bootstrap CI on identical
 (trace, seed) cells.  A preset enters the allowlist only by passing here —
 and drifts out loudly, not silently, when either engine changes."""
+import dataclasses
 import json
+import math
 from pathlib import Path
 
 import numpy as np
 import pytest
 
 from repro.core.policies import PolicySpec, partition_policies
-from repro.core.types import ClusterSpec
+from repro.core.types import AdaptiveConfig, ClusterSpec
 from repro.experiments.runner import ExperimentSpec, TraceRef
 from repro.experiments.surrogate import (CALIBRATED, CALIBRATION_SEEDS,
                                          calibrate, run_surrogate,
                                          surrogate_descriptor,
                                          surrogate_hash)
 from repro.simcluster.surrogate import (SURROGATE_ENGINE_ID,
+                                        TAIL_INFLATION, SurrogateCellInputs,
                                         SurrogateUnsupported, build_cell,
                                         lower_policy, run_batch, run_cell,
                                         surrogate_supported)
-from repro.simcluster.traces import PRESETS, generate_trace
+from repro.simcluster.traces import (PRESETS, _stable_seed, generate_trace,
+                                     trace_from_rows)
+from repro.simcluster.workloads import default_deadline
 
 _CLUSTER = ClusterSpec(num_machines=6, vms_per_machine=2, replication=1)
 
@@ -257,6 +262,131 @@ def test_every_unsupported_registry_policy_raises():
         assert exc.value.label == name
     for name in supported:
         lower_policy(name)
+
+
+# ---------------------------------------------------------------------------
+# cell inputs built without placing blocks
+# ---------------------------------------------------------------------------
+
+def _placed_build_cell(trace, cluster, policy, seed):
+    """``build_cell`` as it was while it placed every block: job specs
+    with their HDFS placements, ``c_repl`` each job's mean count of
+    distinct holders a block."""
+    jobs = trace.job_specs(cluster)
+    n = len(jobs)
+    rng = np.random.default_rng(
+        _stable_seed("surrogate-jitter", trace.name, trace.seed, seed))
+    submit = np.array([j.submit_time for j in jobs], np.float32)
+    dl_rel = np.array([j.deadline for j in jobs], np.float32)
+    u_m = np.array([j.u_m for j in jobs], np.float32)
+    v_r = np.array([j.v_r for j in jobs], np.float32)
+    map_t = np.empty(n, np.float32)
+    red_t = np.empty(n, np.float32)
+    c_repl = np.empty(n, np.float32)
+    for i, j in enumerate(jobs):
+        prof = j.profile
+        cv = getattr(prof, "time_cv", 0.08)
+        z_m, z_r = rng.standard_normal(2)
+        jitter_m = math.exp(cv * z_m / math.sqrt(max(j.u_m, 1)))
+        jitter_r = math.exp(cv * z_r / math.sqrt(max(j.v_r, 1)))
+        map_t[i] = prof.map_time * TAIL_INFLATION * jitter_m
+        red_t[i] = ((prof.reduce_time + j.u_m * prof.shuffle_time_per_pair)
+                    * TAIL_INFLATION * jitter_r)
+        c_repl[i] = float(np.mean(
+            [len(set(p)) for p in j.block_placement[:j.u_m]]))
+    remote_mult = 1.0 + (jobs[0].profile.remote_penalty
+                         * cluster.remote_penalty_scale)
+    map_slots = float(cluster.num_nodes * cluster.base_map_slots)
+    red_slots = float(cluster.num_nodes * cluster.base_reduce_slots)
+    total_work = (float(np.sum(u_m * map_t)) * remote_mult / map_slots
+                  + float(np.sum(v_r * red_t)) / red_slots)
+    adaptive = cluster.adaptive if isinstance(cluster.adaptive,
+                                              AdaptiveConfig) else AdaptiveConfig()
+    return SurrogateCellInputs(
+        submit=submit, dl_abs=submit + dl_rel, u_m=u_m, v_r=v_r,
+        map_t=map_t, red_t=red_t, c_repl=c_repl,
+        n_nodes=cluster.num_nodes, n_machines=cluster.num_machines,
+        map_slots=map_slots, red_slots=red_slots, remote_mult=remote_mult,
+        policy=lower_policy(policy),
+        overload_pending_factor=adaptive.overload_pending_factor,
+        overload_active_factor=adaptive.overload_active_factor,
+        horizon=float(np.max(submit)) + 3.0 * total_work + 900.0,
+        job_ids=[j.job_id for j in jobs],
+        workloads=[j.profile.name for j in jobs],
+        input_gb=[j.input_size_gb for j in jobs],
+        deadlines_rel=dl_rel)
+
+
+def _job_type_trace():
+    """A job-type stream shaped like FB-2009's: mostly one-block sorts,
+    a few large greps and a transform, skewed placement (the default)."""
+    types = [("sort", 2.1e-05)] * 30 + [("sort", 0.000381)] * 4 + [
+        ("grep", 23.0), ("wordcount", 25.5), ("inverted_index", 4.18)]
+    rows = [(w, gb, default_deadline(w, gb), 55.0 * i)
+            for i, (w, gb) in enumerate(types)]
+    return trace_from_rows("fb2009-like", rows, seed=11)
+
+
+_PLACEMENT_CASES = {
+    # the paper's cell: skewed placement, one replica a block
+    "heavy_tail_20x2_r1": (
+        lambda: generate_trace(PRESETS["heavy_tail"], seed=3),
+        ClusterSpec(num_machines=20, vms_per_machine=2, replication=1)),
+    # a fleet-scale job-type stream, triple replication
+    "job_types_600x2_r3": (
+        _job_type_trace,
+        ClusterSpec(num_machines=600, vms_per_machine=2, replication=3)),
+    # uniform placement
+    "uniform_skew0_6x2_r3": (
+        lambda: generate_trace(
+            dataclasses.replace(PRESETS["mix_small"], skew=0.0), seed=1),
+        ClusterSpec(num_machines=6, vms_per_machine=2, replication=3)),
+    # more replicas than VMs: each block on every VM
+    "replication_above_nodes": (
+        lambda: generate_trace(PRESETS["mix_small"], seed=2),
+        ClusterSpec(num_machines=1, vms_per_machine=2, replication=3)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PLACEMENT_CASES))
+def test_build_cell_is_byte_identical_to_the_placed_build(case):
+    """Building rows from the trace, with ``c_repl`` the cluster's distinct
+    holders, gives every input the placed build gave: each array byte for
+    byte, each list and scalar by value."""
+    make_trace, cluster = _PLACEMENT_CASES[case]
+    trace = make_trace()
+    for policy, seed in (("proposed", 0), ("edf_nopark", 5)):
+        got = build_cell(trace, cluster, policy, seed)
+        want = _placed_build_cell(trace, cluster, policy, seed)
+        for f in dataclasses.fields(SurrogateCellInputs):
+            a, b = getattr(got, f.name), getattr(want, f.name)
+            if isinstance(b, np.ndarray):
+                assert (a.dtype, a.shape) == (b.dtype, b.shape), f.name
+                assert a.tobytes() == b.tobytes(), f.name
+            else:
+                assert type(a) is type(b) and a == b, f.name
+
+
+def test_build_cell_places_no_block(monkeypatch):
+    """The surrogate path never calls ``place_blocks``: with it raising,
+    ``build_cell`` and ``build_inputs`` still build every cell, while the
+    event engine's ``job_specs`` does reach it."""
+    from repro.experiments.surrogate import build_inputs
+    from repro.simcluster import traces
+
+    def place_blocks(*args, **kw):
+        raise AssertionError("a block was placed")
+
+    monkeypatch.setattr(traces, "place_blocks", place_blocks)
+    trace = generate_trace(PRESETS["mix_small"], seed=0)
+    cell = build_cell(trace, _CLUSTER, "proposed", 0)
+    assert cell.n_jobs == len(trace.jobs)
+    assert (cell.c_repl == 1.0).all()
+    cells = list(_small_spec().cells())
+    _, inputs = build_inputs(cells)
+    assert len(inputs) == len(cells) == 4
+    with pytest.raises(AssertionError, match="a block was placed"):
+        trace.job_specs(_CLUSTER)
 
 
 # ---------------------------------------------------------------------------

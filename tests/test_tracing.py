@@ -370,6 +370,32 @@ def test_pack_span_counts_the_lanes_packing_reorders(monkeypatch):
     assert reordered(lanes) == [out_of_order]
 
 
+def test_job_specs_span_counts_the_blocks_left_unplaced(monkeypatch):
+    """``repro.surrogate.job_specs`` opens once a cell built and carries
+    ``blocks``, the cell's map blocks (its summed ``u_m``), none of which
+    is placed."""
+    import contextlib
+    import jax
+    from repro.simcluster.surrogate import build_cell
+    from repro.simcluster.traces import PRESETS, generate_trace
+    seen = []
+
+    def annotation(name, **args):
+        seen.append((name, args))
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
+    cluster = ClusterSpec(num_machines=20, vms_per_machine=2, replication=1)
+    for preset in ("mix_small", "heavy_tail"):
+        seen.clear()
+        cell = build_cell(generate_trace(PRESETS[preset], seed=1), cluster,
+                          "proposed", 0)
+        (args,) = [a for name, a in seen
+                   if name == "repro.surrogate.job_specs"]
+        assert args == {"blocks": int(cell.u_m.sum())}
+        assert args["blocks"] > cell.n_jobs
+
+
 def test_cli_surrogate_profile_records_the_program_spans(tmp_path, capsys):
     """``surrogate --profile DIR`` leaves a profiler trace whose host plane
     holds the sweep's ``repro.surrogate.*`` spans."""

@@ -6,10 +6,11 @@ import pytest
 
 from repro.core.types import ClusterSpec
 from repro.simcluster.workloads import (PAPER_TABLE2_ROWS, WORKLOADS,
-                                        default_deadline, make_job,
-                                        n_map_tasks, n_reduce_tasks,
-                                        paper_cluster, paper_job_mix,
-                                        paper_table2_jobs, place_blocks)
+                                        default_deadline, distinct_holders,
+                                        make_job, n_map_tasks,
+                                        n_reduce_tasks, paper_cluster,
+                                        paper_job_mix, paper_table2_jobs,
+                                        place_blocks, task_counts)
 
 
 def test_n_map_tasks_block_math():
@@ -18,6 +19,13 @@ def test_n_map_tasks_block_math():
     assert n_map_tasks(1.01) == 9         # partial block => extra map task
     assert n_map_tasks(0.05) == 1         # tiny inputs still get one task
     assert n_map_tasks(0.0) == 1
+
+
+def test_task_counts_pair_the_map_and_reduce_counts():
+    for w in WORKLOADS:
+        for gb in (0.0, 2.1e-05, 1.01, 230.0):
+            assert task_counts(w, gb) == (n_map_tasks(gb),
+                                          n_reduce_tasks(w, gb))
 
 
 def test_n_reduce_tasks_ratio_and_floor():
@@ -55,19 +63,34 @@ def test_make_job_fields_and_placement():
         assert 0 <= placement[0] < spec.num_nodes
 
 
-def test_place_blocks_replication_and_distinctness():
-    spec = ClusterSpec(num_machines=4, vms_per_machine=2, replication=3)
+_PLACEMENT_CLUSTERS = {
+    "defaults": {},
+    "4x2_r1": dict(num_machines=4, vms_per_machine=2, replication=1),
+    "4x2_r3": dict(num_machines=4, vms_per_machine=2, replication=3),
+    "1x2_r3_capped": dict(num_machines=1, vms_per_machine=2, replication=3),
+    "600x2_r3": dict(num_machines=600, vms_per_machine=2, replication=3),
+}
+
+
+@pytest.mark.parametrize("skew", [0.0, 1.0])
+@pytest.mark.parametrize("cluster", sorted(_PLACEMENT_CLUSTERS))
+def test_place_blocks_replication_and_distinctness(cluster, skew):
+    """Every block gets exactly ``distinct_holders`` distinct VMs — the
+    replication capped by the cluster's VMs — on the uniform and the
+    skewed path, from the paper's 8 VMs to a 1,200-VM fleet."""
+    spec = ClusterSpec(**_PLACEMENT_CLUSTERS[cluster])
+    assert distinct_holders(spec) == min(spec.replication, spec.num_nodes)
     rng = random.Random(1)
-    for skew in (0.0, 1.0):
-        placements = place_blocks(16, spec, rng, skew=skew)
+    for replication in (None, 1, 2):
+        holders = distinct_holders(spec, replication)
+        assert holders == min(replication or spec.replication,
+                              spec.num_nodes)
+        placements = place_blocks(16, spec, rng, replication=replication,
+                                  skew=skew)
         assert len(placements) == 16
         for p in placements:
-            assert len(p) == 3 == len(set(p))       # distinct replicas
+            assert len(p) == holders == len(set(p))     # distinct replicas
             assert all(0 <= n < spec.num_nodes for n in p)
-    # replication capped by cluster size
-    tiny = ClusterSpec(num_machines=1, vms_per_machine=2, replication=3)
-    for p in place_blocks(4, tiny, random.Random(0)):
-        assert len(p) == 2
 
 
 def test_place_blocks_skew_concentrates_load():
