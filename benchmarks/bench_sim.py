@@ -2,8 +2,9 @@
 
 Runs large-fleet scenarios from ``repro.simcluster.largescale`` on the
 optimized (incremental-index) engine and, where the seed engine can run them
-at all, on the frozen legacy engine, and writes ``BENCH_sim.json`` at the
-repo root with wall time, events/sec and the speedup ratio per scenario.
+at all, on the frozen legacy engine, and reports wall time, events/sec and
+the speedup ratio per scenario as JSON: on stdout, or in ``--out PATH``.
+These are host-CPU numbers; no baseline of them is kept in the repo.
 
 Modes:
 
@@ -82,6 +83,12 @@ def _summarize(result, wall: float, engine: str, commit: str) -> dict:
     }
 
 
+def log(*parts, **kw) -> None:
+    """Progress and the summary go to stderr: stdout carries only the
+    JSON."""
+    print(*parts, file=sys.stderr, **kw)
+
+
 def bench_paper_cluster(seed: int = 3, commit: str = "unknown") -> dict:
     """Paper-sized cluster on both engines (also a live parity check)."""
     out = {}
@@ -151,7 +158,8 @@ def main(argv=None) -> int:
                          "bus enabled: records indexed_traced events/sec, "
                          "the overhead %% and a traced-parity check")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--out", type=Path, default=REPO_ROOT / "BENCH_sim.json")
+    ap.add_argument("--out", type=Path, default=None,
+                    help="write the JSON here (default: print it on stdout)")
     args = ap.parse_args(argv)
 
     commit = git_commit()
@@ -166,27 +174,27 @@ def main(argv=None) -> int:
             ap.error(f"unknown scenario(s) {unknown}; "
                      f"available: {', '.join(sorted(SCENARIOS))}")
         for name in args.scenarios:
-            print(f"[bench] {name} (indexed"
-                  + (" + traced" if args.traced else "") + ") ...",
-                  flush=True)
+            log(f"[bench] {name} (indexed"
+                + (" + traced" if args.traced else "") + ") ...",
+                flush=True)
             results["scenarios"][name] = bench_scenario(
                 name, seed=args.seed, commit=commit, traced=args.traced)
     else:
-        print("[bench] paper cluster (indexed + legacy) ...", flush=True)
+        log("[bench] paper cluster (indexed + legacy) ...", flush=True)
         results["scenarios"]["paper_20x2"] = bench_paper_cluster(commit=commit)
-        print("[bench] smoke_40x2 (indexed) ...", flush=True)
+        log("[bench] smoke_40x2 (indexed) ...", flush=True)
         results["scenarios"]["smoke_40x2"] = bench_scenario(
             "smoke_40x2", seed=args.seed, commit=commit)
         if args.quick:
-            print("[bench] fleet_100x2_sustained (indexed) ...", flush=True)
+            log("[bench] fleet_100x2_sustained (indexed) ...", flush=True)
             results["scenarios"]["fleet_100x2_sustained"] = bench_scenario(
                 "fleet_100x2_sustained", seed=args.seed, commit=commit,
                 traced=args.traced)
-            print("[bench] fleet_100x2_churn (indexed) ...", flush=True)
+            log("[bench] fleet_100x2_churn (indexed) ...", flush=True)
             results["scenarios"]["fleet_100x2_churn"] = bench_scenario(
                 "fleet_100x2_churn", seed=args.seed, commit=commit,
                 traced=args.traced)
-            print("[bench] fleet_100x2_serving (indexed) ...", flush=True)
+            log("[bench] fleet_100x2_serving (indexed) ...", flush=True)
             results["scenarios"]["fleet_100x2_serving"] = bench_scenario(
                 "fleet_100x2_serving", seed=args.seed, commit=commit,
                 traced=args.traced)
@@ -194,39 +202,35 @@ def main(argv=None) -> int:
             # the headline comparison: >=100 machines, >=100 jobs, both
             # engines.  The arrival trace is gap-free so the seed engine's
             # heartbeat deadlock does not bias the measurement.
-            print("[bench] fleet_100x2_sustained (indexed + legacy, "
-                  "the legacy run takes minutes) ...", flush=True)
+            log("[bench] fleet_100x2_sustained (indexed + legacy, "
+                "the legacy run takes minutes) ...", flush=True)
             results["scenarios"]["fleet_100x2_sustained"] = bench_scenario(
                 "fleet_100x2_sustained", seed=args.seed,
                 engines=("indexed", "legacy"), commit=commit,
                 traced=args.traced)
             for name in ("fleet_100x2", "fleet_200x2", "fleet_200x4",
                          "fleet_400x2", "burst_idle_gap"):
-                print(f"[bench] {name} (indexed; impossible on the seed "
-                      "engine: idle-gap deadlock / intractable scan cost) ...",
-                      flush=True)
+                log(f"[bench] {name} (indexed; impossible on the seed "
+                    "engine: idle-gap deadlock / intractable scan cost) ...",
+                    flush=True)
                 results["scenarios"][name] = bench_scenario(
                     name, seed=args.seed, commit=commit)
-            print("[bench] fleet_100x2_churn (indexed; fault injection "
-                  "does not exist on the seed engine) ...", flush=True)
+            log("[bench] fleet_100x2_churn (indexed; fault injection "
+                "does not exist on the seed engine) ...", flush=True)
             results["scenarios"]["fleet_100x2_churn"] = bench_scenario(
                 "fleet_100x2_churn", seed=args.seed, commit=commit)
-            print("[bench] fleet_100x2_serving (indexed; the serving layer "
-                  "does not exist on the seed engine) ...", flush=True)
+            log("[bench] fleet_100x2_serving (indexed; the serving layer "
+                "does not exist on the seed engine) ...", flush=True)
             results["scenarios"]["fleet_100x2_serving"] = bench_scenario(
                 "fleet_100x2_serving", seed=args.seed, commit=commit)
 
     results["total_wall_time_s"] = round(time.perf_counter() - t_start, 2)
-    if args.out.exists():
-        # bench_surrogate.py owns the "surrogate" section of the same
-        # file; a scenario re-run must not drop it.  An empty file (e.g.
-        # a fresh mktemp target) carries nothing to preserve.
-        prior_text = args.out.read_text()
-        prior = json.loads(prior_text) if prior_text.strip() else {}
-        if "surrogate" in prior:
-            results["surrogate"] = prior["surrogate"]
-    args.out.write_text(json.dumps(results, indent=2) + "\n")
-    print(f"[bench] wrote {args.out}")
+    text = json.dumps(results, indent=2) + "\n"
+    if args.out is None:
+        sys.stdout.write(text)
+    else:
+        args.out.write_text(text)
+        log(f"[bench] wrote {args.out}")
     for name, r in results["scenarios"].items():
         line = f"  {name}: "
         if "indexed" in r:
@@ -238,7 +242,7 @@ def main(argv=None) -> int:
             line += (f", traced {r['indexed_traced']['events_per_sec']} ev/s "
                      f"({r['trace_overhead_pct']:+.1f}% overhead, "
                      f"traced_parity={r['traced_parity']})")
-        print(line)
+        log(line)
     return 0
 
 

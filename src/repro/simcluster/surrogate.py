@@ -38,14 +38,12 @@ reduce-aware latch (``overload: reduce_aware``).  Those live on event-level
 signals (per-machine donor timing) with no fluid equivalent; the policies
 ``adaptive`` and ``adaptive_ra`` stay oracle-only.
 
-Determinism contract, pinned on CPU by ``tests/test_surrogate.py`` and on
-one TPU v5e by ``chip_smoke.py`` (80 fleet cells at 200x2): per (config,
-seed) the result is byte-stable on a platform; a batch of one through
-``vmap`` is bit-identical to the unbatched kernel; and a cell's result is
-invariant to the batch it rides in and to the sub-batch cap — padding
-buckets (``_bucket``) are a function of the cell alone, never of its batch
-mates.  Across platforms the result is not bitwise: on the v5e the
-launch-mass sums (hence locality) differ from the CPU's by ~3e-7 while
+Determinism contract, pinned on CPU by ``tests/test_surrogate.py``: per
+(config, seed) the result is byte-stable on a platform, and a cell's
+result is invariant to the batch it rides in and to the sub-batch cap —
+padding buckets (``_bucket``) are a function of the cell alone, never of
+its batch mates.  Across platforms the result is not bitwise: on a TPU
+v5e the launch-mass sums (hence locality) differ from the CPU's by ~3e-7 while
 finish times, finished jobs and met deadlines match, so the sweep cache
 keys surrogate cells on the platform off the CPU.
 """
@@ -423,7 +421,7 @@ def _priority_alloc(jnp, demand, capacity):
     return jnp.clip(capacity - before, 0.0, demand)
 
 
-def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
+def _make_kernel(n_jobs: int, n_steps: int):
     """Build the single-cell scan kernel for a (jobs, steps) bucket.
 
     The dynamics are a *discrete-lag fluid*: pending task mass launches
@@ -440,14 +438,11 @@ def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
     Returns ``kernel(packed) -> outputs`` where outputs are per-job
     ``finish`` times (``_INF`` = unfinished), accumulated local/remote
     launch mass, the latched-step count, and ``chunks``: the int32 count
-    of ``CHUNK``-step chunks the early-exit loop ran (``n_steps // CHUNK``
-    on the ``diag`` scan).  Each stage of a step runs under a
-    ``jax.named_scope`` (``KERNEL_STAGES``), which names its operations in
-    the compiled executable (:func:`kernel_stages`).  ``diag=True``
-    additionally stacks per-step cluster aggregates (active jobs, queued
-    mass, free slots, launch totals, launch-weighted locality, crowding,
-    latch) — the observability hook calibration probes use.  Pure jnp:
-    safe under both direct call and ``vmap``."""
+    of ``CHUNK``-step chunks the early-exit loop ran.  Each stage of a
+    step runs under a ``jax.named_scope`` (``KERNEL_STAGES``), which names
+    its operations in the compiled executable (:func:`kernel_stages`).
+    Pure jnp, written for one cell: :func:`_compiled` maps it over a
+    batch with ``vmap``."""
     import jax
     import jax.numpy as jnp
 
@@ -540,6 +535,10 @@ def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
                     _fair_waterfill(jnp, off2, spare),
                     _priority_alloc(jnp, off2, spare))
                 launch = launch1 + launch2
+                # ``blocked`` and ``lf`` (park stage) have no reader.
+                # Dropping them leaves the optimized executable as it is
+                # but changes the lowered module, and with it the compile
+                # cache's key: drop them with the next change to the math.
                 blocked = jnp.sum(waiting)
                 pend_m = jnp.maximum(pend_m - launch, 0.0)
                 pend_m = jnp.where(pend_m <= 0.01, 0.0, pend_m)
@@ -620,19 +619,8 @@ def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
                     & (red_left <= _EPS)
                 finish = jnp.where(done & (finish >= _INF), t + dt, finish)
                 lsteps = lsteps + latch.astype(jnp.float32)
-            ys = None
-            if diag:
-                lsum = jnp.maximum(jnp.sum(launch), _EPS)
-                ys = {"active": active, "pending": pending,
-                      "free_m": free_m, "free_r": free_r,
-                      "waiting": jnp.sum(waiting), "blocked": blocked,
-                      "launched_m": jnp.sum(launch),
-                      "launched_r": jnp.sum(launch_r),
-                      "lf": jnp.sum(lf * launch) / lsum,
-                      "chi": chi,
-                      "latch": latch.astype(jnp.float32)}
             return (pend_m, ring_m, pend_r, ring_r, park_s, park_x,
-                    finish, loc_acc, rem_acc, latch, lsteps), ys
+                    finish, loc_acc, rem_acc, latch, lsteps), None
 
         init = (p["map_mass0"],
                 jnp.zeros((n_jobs, L), jnp.float32),
@@ -645,50 +633,41 @@ def _make_kernel(n_jobs: int, n_steps: int, diag: bool = False):
                 jnp.zeros((n_jobs,), jnp.float32),
                 jnp.asarray(False),
                 jnp.asarray(0.0, jnp.float32))
-        if diag:
-            its = jnp.arange(n_steps, dtype=jnp.int32)
-            final, ys = jax.lax.scan(step, init, its)
-            chunks = jnp.asarray(n_steps // CHUNK, jnp.int32)
-        else:
-            # early exit at chunk granularity: once every real job has
-            # finished, further steps are exact no-ops (no pending mass,
-            # empty rings, latch released), so skipping them is
-            # bit-identical to integrating the full horizon — the scan
-            # just stops paying for the drain tail.
-            n_chunks = max(n_steps // CHUNK, 1)
+        # early exit at chunk granularity: once every real job has
+        # finished, further steps are exact no-ops (no pending mass, empty
+        # rings, latch released), so skipping them is bit-identical to
+        # integrating the full horizon — the scan just stops paying for
+        # the drain tail.
+        n_chunks = max(n_steps // CHUNK, 1)
 
-            def unfinished(carry):
-                with jax.named_scope("early_exit"):
-                    return jnp.any((carry[6] >= _INF) & (pad_mask > 0.5))
+        def unfinished(carry):
+            with jax.named_scope("early_exit"):
+                return jnp.any((carry[6] >= _INF) & (pad_mask > 0.5))
 
-            def cond(state):
-                carry, c = state
-                return (c < n_chunks) & unfinished(carry)
+        def cond(state):
+            carry, c = state
+            return (c < n_chunks) & unfinished(carry)
 
-            def body(state):
-                carry, c = state
-                its = c * CHUNK + jnp.arange(CHUNK, dtype=jnp.int32)
-                carry, _ = jax.lax.scan(step, carry, its)
-                return (carry, c + 1)
+        def body(state):
+            carry, c = state
+            its = c * CHUNK + jnp.arange(CHUNK, dtype=jnp.int32)
+            carry, _ = jax.lax.scan(step, carry, its)
+            return (carry, c + 1)
 
-            # under vmap each lane keeps its own count: a finished lane's
-            # carry, counter included, stops changing
-            final, chunks = jax.lax.while_loop(
-                cond, body, (init, jnp.asarray(0, jnp.int32)))
-            ys = None
+        # under vmap each lane keeps its own count: a finished lane's
+        # carry, counter included, stops changing
+        final, chunks = jax.lax.while_loop(
+            cond, body, (init, jnp.asarray(0, jnp.int32)))
         (pend_m, _, pend_r, _, _, _, finish, loc_acc, rem_acc, _,
          lsteps) = final
-        out = {"finish": finish, "local": loc_acc, "remote": rem_acc,
-               "map_rem": pend_m, "red_rem": pend_r,
-               "latched_steps": lsteps, "chunks": chunks}
-        if diag:
-            out["diag"] = ys
-        return out
+        return {"finish": finish, "local": loc_acc, "remote": rem_acc,
+                "map_rem": pend_m, "red_rem": pend_r,
+                "latched_steps": lsteps, "chunks": chunks}
 
     return kernel
 
 
-_KERNEL_CACHE: Dict[Tuple[int, int, bool, bool], object] = {}
+_KERNEL_CACHE: Dict[Tuple[int, int], object] = {}
 
 #: the checkout root (``src/repro/simcluster/`` → three levels up)
 _CHECKOUT = Path(__file__).resolve().parents[3]
@@ -709,42 +688,23 @@ def use_compile_cache() -> str:
     return jax.config.jax_compilation_cache_dir
 
 
-#: cells per vmapped sub-batch in run_batch.  On the CPU it is large enough
-#: to amortize dispatch, small enough that the scan carry stays
-#: cache-resident.  On one TPU v5e the per-cell cost is nearly flat in the
-#: cap: 80 fleet cells (1024 jobs x 4096 steps) took 14.4 s at 64, 13.4 s
-#: at 16 and 18.6 s at 1, so the device's per-step work grows with the
-#: batch and the cap mostly decides how many shapes compile (each
-#: sub-batch size, remainder included, is its own compile).
-#: Overridable per-call (``run_batch(..., max_batch=...)``) or process-wide
-#: via ``REPRO_SURROGATE_MAX_BATCH``; per-cell results are bit-identical
-#: at every cap, on the CPU and on the chip.
+#: cells per vmapped sub-batch in run_batch.  On one TPU v5e the per-cell
+#: cost is nearly flat in the cap: 80 fleet cells (1024 jobs x 4096 steps)
+#: took 14.4 s at 64, 13.4 s at 16 and 18.6 s at 1, so the cap mostly
+#: decides how many shapes compile (each sub-batch size, remainder
+#: included, is its own compile).
 _MAX_BATCH = 64
 
 
-def _resolve_max_batch(max_batch: Optional[int] = None) -> int:
-    """Sub-batch cap for ``run_batch``: explicit kwarg beats the
-    ``REPRO_SURROGATE_MAX_BATCH`` env var beats the built-in default."""
-    if max_batch is None:
-        env = os.environ.get("REPRO_SURROGATE_MAX_BATCH")
-        if env:
-            max_batch = int(env)
-        else:
-            return _MAX_BATCH
-    if max_batch < 1:
-        raise ValueError(f"max_batch must be >= 1, got {max_batch}")
-    return max_batch
-
-
-def _compiled(n_jobs: int, n_steps: int, batched: bool, diag: bool = False):
-    """jit-compiled kernel per (bucket, batched) — the cache keeps repeat
-    sweeps from re-tracing."""
+def _compiled(n_jobs: int, n_steps: int):
+    """The jit-compiled batched kernel per (jobs, steps) bucket — the cache
+    keeps repeat sweeps from re-tracing."""
     import jax
-    key = (n_jobs, n_steps, batched, diag)
+    key = (n_jobs, n_steps)
     fn = _KERNEL_CACHE.get(key)
     if fn is None:
-        kernel = _make_kernel(n_jobs, n_steps, diag=diag)
-        fn = jax.jit(jax.vmap(kernel) if batched else kernel)
+        kernel = _make_kernel(n_jobs, n_steps)
+        fn = jax.jit(jax.vmap(kernel))
         _KERNEL_CACHE[key] = fn
     return fn
 
@@ -875,7 +835,7 @@ def kernel_stages(n_jobs: int, n_steps: int, lanes: int) -> Dict[str, str]:
     shapes = {k: jax.ShapeDtypeStruct(
         (lanes, n_jobs) if k in _JOB_FIELDS else (lanes,), np.float32)
         for k in _JOB_FIELDS + _SCALAR_FIELDS}
-    return hlo_stages(_compiled(n_jobs, n_steps, batched=True).lower(
+    return hlo_stages(_compiled(n_jobs, n_steps).lower(
         shapes).compile().as_text())
 
 
@@ -909,8 +869,6 @@ class SurrogateResult:
     locality_rate: float
     latched_steps: float
     jobs: List[SurrogateJob]
-    # per-step cluster aggregates, present when run with diag=True
-    diag: Optional[Dict[str, np.ndarray]] = None
 
     def throughput_jobs_per_hour(self) -> float:
         if self.makespan <= 0:
@@ -959,32 +917,13 @@ def _unpack_result(cell: SurrogateCellInputs, out: Dict[str, np.ndarray]
         latched_steps=latched, jobs=jobs)
 
 
-def run_cell(cell: SurrogateCellInputs,
-             diag: bool = False) -> SurrogateResult:
-    """Integrate one cell through the *unbatched* kernel (the reference
-    path the batch determinism tests compare against).  ``diag=True``
-    attaches per-step cluster aggregates as ``result.diag`` (dict of
-    time-series arrays) for calibration probes."""
-    packed = pack_cell(cell)
-    out = _compiled(cell.padded_jobs(), cell.n_steps(),
-                    batched=False, diag=diag)(packed)
-    traj = out.pop("diag", None)
-    result = _unpack_result(cell,
-                            {k: np.asarray(v) for k, v in out.items()})
-    if traj is not None:
-        result.diag = {k: np.asarray(v) for k, v in traj.items()}
-    return result
-
-
-def run_batch(cells: Sequence[SurrogateCellInputs], *,
-              max_batch: Optional[int] = None) -> List[SurrogateResult]:
+def run_batch(cells: Sequence[SurrogateCellInputs]
+              ) -> List[SurrogateResult]:
     """Integrate many cells, grouped by (jobs, steps) bucket and run
-    through ``vmap`` in sub-batches of ``max_batch`` (default ``_MAX_BATCH``,
-    overridable via ``REPRO_SURROGATE_MAX_BATCH``) — a handful of XLA
+    through ``vmap`` in sub-batches of ``_MAX_BATCH`` — a handful of XLA
     computations for thousands of cells per call.  Results come back in
-    input order and are bit-identical to ``run_cell`` on each cell alone,
-    whatever the sub-batch cap (pinned by the fuzz suite)."""
-    cap = _resolve_max_batch(max_batch)
+    input order, and a cell's result does not depend on its batch mates
+    or on the sub-batch cap (pinned by ``tests/test_surrogate.py``)."""
     with span("repro.surrogate.run_batch"):
         groups: Dict[Tuple[int, int], List[int]] = {}
         for i, cell in enumerate(cells):
@@ -996,8 +935,8 @@ def run_batch(cells: Sequence[SurrogateCellInputs], *,
             # batch composition (pinned by the fuzz suite), and moderate
             # batches keep the scan carry cache-resident — a single huge
             # vmap thrashes
-            for lo in range(0, len(idxs), cap):
-                part = idxs[lo:lo + cap]
+            for lo in range(0, len(idxs), _MAX_BATCH):
+                part = idxs[lo:lo + _MAX_BATCH]
                 # lanes whose jobs the priority-order layout moves
                 reordered = sum(
                     bool(np.any(priority_order(cells[i])
@@ -1011,7 +950,7 @@ def run_batch(cells: Sequence[SurrogateCellInputs], *,
                 # the executable; a compile shows here, under its shape
                 with span("repro.surrogate.dispatch", lanes=len(part),
                           jobs=jp, steps=ts):
-                    out = _compiled(jp, ts, batched=True)(stacked)
+                    out = _compiled(jp, ts)(stacked)
                 with span("repro.surrogate.fetch"):
                     out = {k: np.asarray(v) for k, v in out.items()}
                 chunks = out["chunks"]

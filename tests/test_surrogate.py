@@ -24,7 +24,7 @@ from repro.experiments.surrogate import (CALIBRATED, CALIBRATION_SEEDS,
 from repro.simcluster.surrogate import (SURROGATE_ENGINE_ID,
                                         TAIL_INFLATION, SurrogateCellInputs,
                                         SurrogateUnsupported, build_cell,
-                                        lower_policy, run_batch, run_cell,
+                                        lower_policy, run_batch,
                                         surrogate_supported)
 from repro.simcluster.traces import (PRESETS, _stable_seed, generate_trace,
                                      trace_from_rows)
@@ -165,13 +165,19 @@ def test_unsupported_grid_rejected_before_any_work(tmp_path):
 # property pins (fuzz tier)
 # ---------------------------------------------------------------------------
 
+_LOWERABLE = ["proposed", "fair", "fifo", "delay", "edf_nopark"]
+
+
 @pytest.mark.fuzz
-@pytest.mark.parametrize("policy", ["proposed", "fair", "fifo", "delay",
-                                    "edf_nopark"])
-def test_batch_of_one_matches_run_cell(policy):
-    cell = _cell(policy=policy)
-    assert _fingerprint(run_batch([cell])[0]) == \
-        _fingerprint(run_cell(cell))
+@pytest.mark.parametrize("policy", _LOWERABLE)
+def test_batch_of_one_matches_its_lane_in_a_mixed_batch(policy):
+    """A cell alone answers exactly as it does in one vmapped batch with
+    cells of every other lowerable policy."""
+    cells = [_cell(policy=p) for p in _LOWERABLE]
+    assert len({(c.padded_jobs(), c.n_steps()) for c in cells}) == 1
+    lane = _LOWERABLE.index(policy)
+    assert _fingerprint(run_batch([cells[lane]])[0]) == \
+        _fingerprint(run_batch(cells)[lane])
 
 
 @pytest.mark.fuzz
@@ -195,8 +201,8 @@ def test_batch_order_and_size_invariance():
 
 @pytest.mark.fuzz
 def test_max_batch_override_is_result_invariant(monkeypatch):
-    """The sub-batch cap is a pure performance knob: kwarg and env-var
-    overrides resplit the vmap without moving a single byte of output."""
+    """The sub-batch cap only decides how the vmap is split: a smaller
+    cap resplits it without moving a single byte of output."""
     import repro.simcluster.surrogate as sg
     assert sg._MAX_BATCH == 64                       # pinned default
     cells = [_cell(policy=p, seed=s)
@@ -204,27 +210,8 @@ def test_max_batch_override_is_result_invariant(monkeypatch):
                           ("delay", 0), ("proposed", 3)]]
     base = [_fingerprint(r) for r in run_batch(cells)]
     for cap in (1, 2, 3):
-        assert base == [_fingerprint(r)
-                        for r in run_batch(cells, max_batch=cap)], cap
-    monkeypatch.setenv("REPRO_SURROGATE_MAX_BATCH", "2")
-    assert base == [_fingerprint(r) for r in run_batch(cells)]
-    # the explicit kwarg wins over the env var
-    assert base == [_fingerprint(r) for r in run_batch(cells, max_batch=4)]
-
-
-def test_max_batch_resolution_precedence(monkeypatch):
-    from repro.simcluster.surrogate import _resolve_max_batch
-    monkeypatch.delenv("REPRO_SURROGATE_MAX_BATCH", raising=False)
-    assert _resolve_max_batch() == 64
-    assert _resolve_max_batch(7) == 7
-    monkeypatch.setenv("REPRO_SURROGATE_MAX_BATCH", "16")
-    assert _resolve_max_batch() == 16
-    assert _resolve_max_batch(3) == 3                # kwarg beats env
-    with pytest.raises(ValueError, match=">= 1"):
-        _resolve_max_batch(0)
-    monkeypatch.setenv("REPRO_SURROGATE_MAX_BATCH", "-5")
-    with pytest.raises(ValueError, match=">= 1"):
-        _resolve_max_batch()
+        monkeypatch.setattr(sg, "_MAX_BATCH", cap)
+        assert base == [_fingerprint(r) for r in run_batch(cells)], cap
 
 
 @pytest.mark.fuzz
@@ -233,17 +220,20 @@ def test_byte_determinism_per_config_seed(seed):
     """Two fresh integrations of the same (config, seed) — including a
     fresh XLA trace — agree byte-for-byte on cpu."""
     import repro.simcluster.surrogate as sg
-    a = _fingerprint(run_cell(_cell(seed=seed)))
+    a = _fingerprint(run_batch([_cell(seed=seed)])[0])
     sg._KERNEL_CACHE.clear()
-    b = _fingerprint(run_cell(_cell(seed=seed)))
+    b = _fingerprint(run_batch([_cell(seed=seed)])[0])
     assert a == b
 
 
 @pytest.mark.fuzz
 def test_seed_and_policy_actually_move_the_result():
-    base = _fingerprint(run_cell(_cell(policy="proposed", seed=0)))
-    assert _fingerprint(run_cell(_cell(policy="proposed", seed=1))) != base
-    assert _fingerprint(run_cell(_cell(policy="fifo", seed=0))) != base
+    def answer(**kw):
+        return _fingerprint(run_batch([_cell(**kw)])[0])
+
+    base = answer(policy="proposed", seed=0)
+    assert answer(policy="proposed", seed=1) != base
+    assert answer(policy="fifo", seed=0) != base
 
 
 @pytest.mark.fuzz
@@ -465,7 +455,7 @@ def test_shuffled_cell_packs_and_answers_the_same(policy):
     assert a.keys() == b.keys()
     for k in a:
         assert np.asarray(a[k]).tobytes() == np.asarray(b[k]).tobytes(), k
-    base, moved = run_cell(cell), run_cell(shuffled)
+    base, moved = run_batch([cell])[0], run_batch([shuffled])[0]
     assert [j.job_id for j in moved.jobs] == shuffled.job_ids
     fp_base, fp_moved = _fingerprint(base), _fingerprint(moved)
     assert fp_moved[:4] == fp_base[:4]
@@ -506,15 +496,13 @@ def test_priority_order_keeps_ties_in_index_order_and_padding_last():
 def _stacked(cells):
     import repro.simcluster.surrogate as sg
     packed = [sg.pack_cell(c) for c in cells]
-    return packed, {k: np.stack([q[k] for q in packed]) for k in packed[0]}
+    return {k: np.stack([q[k] for q in packed]) for k in packed[0]}
 
 
 def test_chunks_count_each_lanes_own_loop():
     """In a batch of cells that finish in different chunks, each lane
     reports the chunks its own early exit ran: through the chunk that
-    holds its last finish, or every chunk for a cell that never finishes;
-    the unbatched kernel reports the same, the ``diag`` scan the whole
-    horizon."""
+    holds its last finish, or every chunk for a cell that never finishes."""
     import dataclasses
     import repro.simcluster.surrogate as sg
     base = _cell(policy="fair")
@@ -524,8 +512,7 @@ def test_chunks_count_each_lanes_own_loop():
              for shift in (0.0, 3000.0, 7000.0, 2 * horizon)]
     jp, ts = cells[0].padded_jobs(), cells[0].n_steps()
     assert {(c.padded_jobs(), c.n_steps()) for c in cells} == {(jp, ts)}
-    packed, stacked = _stacked(cells)
-    out = sg._compiled(jp, ts, batched=True)(stacked)
+    out = sg._compiled(jp, ts)(_stacked(cells))
     chunks = np.asarray(out["chunks"])
     assert chunks.dtype == np.int32
     for lane, cell in enumerate(cells):
@@ -536,11 +523,7 @@ def test_chunks_count_each_lanes_own_loop():
         else:
             expected = ts // sg.CHUNK
         assert chunks[lane] == expected, lane
-        alone = sg._compiled(jp, ts, batched=False)(packed[lane])
-        assert int(alone["chunks"]) == chunks[lane]
     assert chunks.tolist() == [1, 3, 5, 8]
-    diag = sg._compiled(jp, ts, batched=False, diag=True)(packed[0])
-    assert int(diag["chunks"]) == ts // sg.CHUNK
 
 
 def test_results_ignore_the_chunk_counter():
@@ -548,9 +531,8 @@ def test_results_ignore_the_chunk_counter():
     output, so records stay what they were."""
     import repro.simcluster.surrogate as sg
     cell = _cell(policy="proposed")
-    out = sg._compiled(cell.padded_jobs(), cell.n_steps(),
-                       batched=False)(sg.pack_cell(cell))
-    out = {k: np.asarray(v) for k, v in out.items()}
+    out = sg._compiled(cell.padded_jobs(), cell.n_steps())(_stacked([cell]))
+    out = {k: np.asarray(v)[0] for k, v in out.items()}
     without = {k: v for k, v in out.items() if k != "chunks"}
     assert _fingerprint(sg._unpack_result(cell, out)) == \
         _fingerprint(sg._unpack_result(cell, without))

@@ -45,15 +45,14 @@ def no_persistent_cache():
 
 
 def _packed_shapes(n_jobs, batch, sharding):
-    """``pack_cell``'s output as shapes: per-job rows and cell scalars,
-    with a leading cell axis when batched."""
+    """``batch`` cells of ``pack_cell``'s output as shapes: per-job rows
+    and cell scalars, behind a leading cell axis."""
     import jax
     import jax.numpy as jnp
-    lead = () if batch is None else (batch,)
-    shapes = {k: jax.ShapeDtypeStruct(lead + (n_jobs,), jnp.float32,
+    shapes = {k: jax.ShapeDtypeStruct((batch, n_jobs), jnp.float32,
                                       sharding=sharding)
               for k in sg._JOB_FIELDS}
-    shapes.update({k: jax.ShapeDtypeStruct(lead, jnp.float32,
+    shapes.update({k: jax.ShapeDtypeStruct((batch,), jnp.float32,
                                            sharding=sharding)
                    for k in sg._SCALAR_FIELDS})
     return shapes
@@ -61,12 +60,12 @@ def _packed_shapes(n_jobs, batch, sharding):
 
 @pytest.mark.parametrize("n_jobs,n_steps,batch", [
     (128, 2048, 64),      # atlas-scale bucket at the default sub-batch
-    (1024, 4096, None),   # the fleet bucket, unbatched (run_cell)
-    (1024, 4096, 4),      # the fleet bucket, vmapped (run_batch)
+    (128, 1024, 4),       # the tuning-query bucket
+    (1024, 4096, 4),      # the fleet bucket
 ])
 def test_surrogate_kernel_compiles_for_v5e(one_chip, no_persistent_cache,
                                            n_jobs, n_steps, batch):
-    fn = sg._compiled(n_jobs, n_steps, batched=batch is not None)
+    fn = sg._compiled(n_jobs, n_steps)
     compiled = fn.lower(_packed_shapes(n_jobs, batch, one_chip)).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < _TEMP_LIMIT, mem
@@ -75,7 +74,7 @@ def test_surrogate_kernel_compiles_for_v5e(one_chip, no_persistent_cache,
 def test_v5e_executable_names_the_ring_stages(one_chip, no_persistent_cache):
     """The chip's compiler keeps the stage scopes: the operations of the
     v5e executable map to the kernel's ring stages."""
-    fn = sg._compiled(128, sg.CHUNK, batched=True)
+    fn = sg._compiled(128, sg.CHUNK)
     compiled = fn.lower(_packed_shapes(128, 4, one_chip)).compile()
     found = set(sg.hlo_stages(compiled.as_text()).values())
     assert {"ring_drain", "ring_scatter", "map_alloc"} <= found
@@ -85,7 +84,7 @@ def test_v5e_executable_has_no_priority_gathers(one_chip, no_persistent_cache):
     """Jobs come packed in priority order, so strict-priority allocation
     is a cumsum on the rows as they lie: no instruction of the v5e
     executable gathers through ``jnp.take``."""
-    fn = sg._compiled(128, sg.CHUNK, batched=True)
+    fn = sg._compiled(128, sg.CHUNK)
     text = fn.lower(_packed_shapes(128, 4, one_chip)).compile().as_text()
     op_names = sg._OP_NAME.findall(text)
     assert any("/map_alloc/" in name for name in op_names)
